@@ -251,4 +251,7 @@ if __name__ == "__main__":
                          "(0 = all at t0; default: preset)")
     ap.add_argument("--slo-ttft", type=float, default=1.0,
                     help="TTFT SLO in seconds for the attainment metric")
-    main(**vars(ap.parse_args()))
+    args = ap.parse_args()
+    from repro.launch.cache import enable_compilation_cache
+    enable_compilation_cache()
+    main(**vars(args))

@@ -2,7 +2,7 @@
 # Tier-1 gate: static contracts (lint + kernel + jaxpr seam checks) + full
 # correctness suite.
 #
-# Usage:  scripts/verify.sh [--lint|--fast|--jax-min] [extra pytest args]
+# Usage:  scripts/verify.sh [--lint|--fast] [extra pytest args]
 #
 #   --lint     run ONLY the static-contract checker
 #              (python -m repro.analysis.check) — AST lint over
@@ -16,12 +16,6 @@
 #              ``multidev`` — everything that spawns a fresh python with
 #              forced host devices).  Quick iteration tier; the FULL suite
 #              remains the default and the PR gate.
-#   --jax-min  run ONLY the compat contract tests with the detected JAX
-#              capped to the 0.4.30 floor of the supported range
-#              (REPRO_COMPAT_ASSUME_JAX) — exercises the oldest-generation
-#              code paths (psum axis-size spelling, no fused-collective
-#              composition) — plus the BENCH_tuning.json layout-sweep
-#              well-formedness check.
 #
 # The static checker replaced the old grep-lint gates: the standing source
 # rules (compat-import, private-backend, removed-wrapper, raw-collective,
@@ -39,15 +33,11 @@ cd "$(dirname "$0")/.."
 
 LINT_ONLY=0
 FAST=0
-JAX_MIN=0
 if [[ "${1:-}" == "--lint" ]]; then
   LINT_ONLY=1
   shift
 elif [[ "${1:-}" == "--fast" ]]; then
   FAST=1
-  shift
-elif [[ "${1:-}" == "--jax-min" ]]; then
-  JAX_MIN=1
   shift
 fi
 
@@ -92,130 +82,6 @@ for layout in ("seq", "hidden"):
                        for c in colls), "no seam_moe ppermute ring traced"
 print("moe a2a census ok: both layouts x both transports")
 EOF
-
-if [[ "$JAX_MIN" == 1 ]]; then
-  echo "== Pallas kernel contracts (repro.analysis.check --kernels) =="
-  # first gate of the floor lane too: the kernel protocol (semaphore
-  # balance, DMA races, ring arithmetic, coverage, budgets) is
-  # JAX-version independent — it must hold before any compat test runs
-  python -m repro.analysis.check --kernels -q
-
-  echo "== compat contract tests at the 0.4.30 floor (REPRO_COMPAT_ASSUME_JAX) =="
-  REPRO_COMPAT_ASSUME_JAX=0.4.30 python -m pytest -x -q tests/test_compat.py "$@"
-  REPRO_COMPAT_ASSUME_JAX=0.4.30 python - <<'EOF'
-from repro import compat
-# the cap never RAISES the version: with jax==0.4.30 actually installed
-# this equals the native detection (and version_summary carries no
-# "assumed" marker — the floor paths run natively there)
-assert compat.JAX_VERSION == (0, 4, 30), compat.JAX_VERSION
-# the floor generation cannot compose fused collective kernels in
-# interpret mode: flux seams must report the decomposed fallback
-assert not compat.fused_collective_kernels_composable()
-print("compat floor assumptions ok:", compat.version_summary())
-EOF
-  echo "== BENCH_tuning.json scatter_axis sweep rows =="
-  python - <<'EOF'
-import json
-doc = json.load(open("experiments/BENCH_tuning.json"))
-rows = doc.get("layout", {}).get("scatter_axis", [])
-assert rows, "BENCH_tuning.json has no scatter_axis sweep rows"
-axes = {r["scatter_axis"] for r in rows}
-assert axes == {"seq", "hidden"}, axes
-for r in rows:
-    assert {"m", "overall_s", "act_bytes", "comm_bytes"} <= set(r), r
-by_m = {}
-for r in rows:
-    by_m.setdefault(r["m"], {})[r["scatter_axis"]] = r
-for m, pair in by_m.items():
-    seq, hid = pair["seq"], pair["hidden"]
-    assert abs(seq["comm_bytes"] - hid["comm_bytes"]) < 1e-6 * max(
-        seq["comm_bytes"], 1.0), (m, "layer-pair comm volume must be "
-                                  "layout-invariant")
-    assert seq["act_bytes"] < hid["act_bytes"], (m, "seq must reduce "
-                                                 "activation residency")
-print(f"BENCH_tuning.json scatter_axis sweep ok: {len(rows)} rows")
-EOF
-  echo "== BENCH_tuning.json MoE a2a rows =="
-  python - <<'EOF'
-import json
-doc = json.load(open("experiments/BENCH_tuning.json"))
-chunks = doc.get("moe", {}).get("a2a_chunks", [])
-assert chunks, "BENCH_tuning.json has no a2a chunk-sweep rows"
-assert len({r["comm_chunks"] for r in chunks}) >= 3, chunks
-for r in chunks:
-    assert {"m", "n", "k", "overall_s", "comm_bytes"} <= set(r), r
-    assert r["comm_bytes"] > 0, r
-a2a_seams = [s for s in doc["seams"] if s["seam"] == "moe_a2a"]
-assert a2a_seams, "no moe_a2a planner row in BENCH_tuning.json"
-modes = {c["mode"] for c in a2a_seams[0]["candidates"]}
-assert {"xla", "decomposed"} <= modes, modes
-print(f"BENCH_tuning.json moe a2a ok: {len(chunks)} chunk rows, "
-      f"pick={a2a_seams[0]['plan']['mode']}")
-EOF
-  echo "== BENCH_tuning.json static tile-budget pruning rows =="
-  python - <<'EOF'
-import json
-from repro.analysis.kernelcheck import tile_budget_ok
-doc = json.load(open("experiments/BENCH_tuning.json"))
-assert doc["seams"], "no planner rows in BENCH_tuning.json"
-for s in doc["seams"]:
-    # every planner row reports how many flux tilings the static VMEM
-    # budget rejected before pricing, and no surviving candidate carries
-    # an infeasible tiling (autotune never times what kernelcheck rejects)
-    assert "pruned" in s, f"seam row missing pruned count: {s['seam']}"
-    assert s["pruned"] >= 0, s
-    for c in s["candidates"]:
-        if c["mode"] == "flux" and c.get("blocks"):
-            assert tile_budget_ok(s["kind"], tuple(c["blocks"])), \
-                (s["seam"], c["blocks"], "infeasible tiling in the table")
-print(f"BENCH_tuning.json pruning ok: {len(doc['seams'])} seam rows, "
-      f"pruned={[s['pruned'] for s in doc['seams']]}")
-EOF
-  echo "== BENCH_tuning.json wire-precision sweep rows =="
-  python - <<'EOF'
-import json
-doc = json.load(open("experiments/BENCH_tuning.json"))
-wire = doc.get("wire", {})
-seams = wire.get("seams", [])
-assert seams, "BENCH_tuning.json has no wire-precision sweep rows"
-budget = wire["max_logit_rmse"]
-assert budget > 0, wire
-kinds = {s["kind"] for s in seams}
-assert {"ag", "rs", "ar", "a2a"} <= kinds, kinds
-for s in seams:
-    dtypes = {r["wire_dtype"] for r in s["rows"]}
-    assert None in dtypes and "int8" in dtypes, (s["seam"], dtypes)
-    for r in s["rows"]:
-        # every row: bytes on the wire, a time estimate, and its
-        # deviation vs the accuracy budget
-        assert r["comm_bytes"] >= 0, (s["seam"], r)
-        assert (r["measured_s"] or r["predicted_s"]) > 0, (s["seam"], r)
-        assert r["logit_rmse"] >= 0, (s["seam"], r)
-        assert r["within_budget"] == (r["logit_rmse"] <= budget), \
-            (s["seam"], r, "within_budget disagrees with the budget")
-        if r["wire_dtype"] is None:
-            assert r["logit_rmse"] == 0.0, (s["seam"], r)
-    # the CHOSEN plan never violates its accuracy budget
-    assert s["plan"]["logit_rmse"] <= budget, (s["seam"], s["plan"])
-    # quantized rows shrink bytes-on-wire vs the fp wire of the same mode
-    for r in s["rows"]:
-        if r["wire_dtype"] is None or r["comm_bytes"] == 0:
-            continue
-        fp = [f for f in s["rows"] if f["wire_dtype"] is None
-              and f["mode"] == r["mode"]
-              and f["comm_chunks"] == r["comm_chunks"]
-              and f["reverse"] == r["reverse"]
-              and f["scatter_axis"] == r["scatter_axis"]]
-        assert fp and r["comm_bytes"] < fp[0]["comm_bytes"], (s["seam"], r)
-assert wire["any_quantized_win"], \
-    "no seam shows an in-budget low-precision wire beating the fp wire"
-picks = {s["seam"]: (s["plan"]["mode"], s["plan"]["wire_dtype"])
-         for s in seams}
-print(f"BENCH_tuning.json wire sweep ok: {len(seams)} seams, "
-      f"budget={budget}, picks={picks}")
-EOF
-  exit 0
-fi
 
 echo "== tier-1 test suite =="
 if [[ "$FAST" == 1 ]]; then
@@ -272,4 +138,106 @@ for r in rows:
 print("BENCH_serving.json ok:",
       ", ".join(f"{r['mode']}={r['tokens_per_s']:.0f} tok/s "
                 f"ttft_p99={r['ttft_s']['p99'] * 1e3:.1f}ms" for r in rows))
+EOF
+
+echo "== BENCH_tuning.json scatter_axis sweep rows =="
+python - <<'EOF'
+import json
+doc = json.load(open("experiments/BENCH_tuning.json"))
+rows = doc.get("layout", {}).get("scatter_axis", [])
+assert rows, "BENCH_tuning.json has no scatter_axis sweep rows"
+axes = {r["scatter_axis"] for r in rows}
+assert axes == {"seq", "hidden"}, axes
+for r in rows:
+    assert {"m", "overall_s", "act_bytes", "comm_bytes"} <= set(r), r
+by_m = {}
+for r in rows:
+    by_m.setdefault(r["m"], {})[r["scatter_axis"]] = r
+for m, pair in by_m.items():
+    seq, hid = pair["seq"], pair["hidden"]
+    assert abs(seq["comm_bytes"] - hid["comm_bytes"]) < 1e-6 * max(
+        seq["comm_bytes"], 1.0), (m, "layer-pair comm volume must be "
+                                  "layout-invariant")
+    assert seq["act_bytes"] < hid["act_bytes"], (m, "seq must reduce "
+                                                 "activation residency")
+print(f"BENCH_tuning.json scatter_axis sweep ok: {len(rows)} rows")
+EOF
+echo "== BENCH_tuning.json MoE a2a rows =="
+python - <<'EOF'
+import json
+doc = json.load(open("experiments/BENCH_tuning.json"))
+chunks = doc.get("moe", {}).get("a2a_chunks", [])
+assert chunks, "BENCH_tuning.json has no a2a chunk-sweep rows"
+assert len({r["comm_chunks"] for r in chunks}) >= 3, chunks
+for r in chunks:
+    assert {"m", "n", "k", "overall_s", "comm_bytes"} <= set(r), r
+    assert r["comm_bytes"] > 0, r
+a2a_seams = [s for s in doc["seams"] if s["seam"] == "moe_a2a"]
+assert a2a_seams, "no moe_a2a planner row in BENCH_tuning.json"
+modes = {c["mode"] for c in a2a_seams[0]["candidates"]}
+assert {"xla", "decomposed"} <= modes, modes
+print(f"BENCH_tuning.json moe a2a ok: {len(chunks)} chunk rows, "
+      f"pick={a2a_seams[0]['plan']['mode']}")
+EOF
+echo "== BENCH_tuning.json static tile-budget pruning rows =="
+python - <<'EOF'
+import json
+from repro.analysis.kernelcheck import tile_budget_ok
+doc = json.load(open("experiments/BENCH_tuning.json"))
+assert doc["seams"], "no planner rows in BENCH_tuning.json"
+for s in doc["seams"]:
+    # every planner row reports how many flux tilings the static VMEM
+    # budget rejected before pricing, and no surviving candidate carries
+    # an infeasible tiling (autotune never times what kernelcheck rejects)
+    assert "pruned" in s, f"seam row missing pruned count: {s['seam']}"
+    assert s["pruned"] >= 0, s
+    for c in s["candidates"]:
+        if c["mode"] == "flux" and c.get("blocks"):
+            assert tile_budget_ok(s["kind"], tuple(c["blocks"])), \
+                (s["seam"], c["blocks"], "infeasible tiling in the table")
+print(f"BENCH_tuning.json pruning ok: {len(doc['seams'])} seam rows, "
+      f"pruned={[s['pruned'] for s in doc['seams']]}")
+EOF
+echo "== BENCH_tuning.json wire-precision sweep rows =="
+python - <<'EOF'
+import json
+doc = json.load(open("experiments/BENCH_tuning.json"))
+wire = doc.get("wire", {})
+seams = wire.get("seams", [])
+assert seams, "BENCH_tuning.json has no wire-precision sweep rows"
+budget = wire["max_logit_rmse"]
+assert budget > 0, wire
+kinds = {s["kind"] for s in seams}
+assert {"ag", "rs", "ar", "a2a"} <= kinds, kinds
+for s in seams:
+    dtypes = {r["wire_dtype"] for r in s["rows"]}
+    assert None in dtypes and "int8" in dtypes, (s["seam"], dtypes)
+    for r in s["rows"]:
+        # every row: bytes on the wire, a time estimate, and its
+        # deviation vs the accuracy budget
+        assert r["comm_bytes"] >= 0, (s["seam"], r)
+        assert (r["measured_s"] or r["predicted_s"]) > 0, (s["seam"], r)
+        assert r["logit_rmse"] >= 0, (s["seam"], r)
+        assert r["within_budget"] == (r["logit_rmse"] <= budget), \
+            (s["seam"], r, "within_budget disagrees with the budget")
+        if r["wire_dtype"] is None:
+            assert r["logit_rmse"] == 0.0, (s["seam"], r)
+    # the CHOSEN plan never violates its accuracy budget
+    assert s["plan"]["logit_rmse"] <= budget, (s["seam"], s["plan"])
+    # quantized rows shrink bytes-on-wire vs the fp wire of the same mode
+    for r in s["rows"]:
+        if r["wire_dtype"] is None or r["comm_bytes"] == 0:
+            continue
+        fp = [f for f in s["rows"] if f["wire_dtype"] is None
+              and f["mode"] == r["mode"]
+              and f["comm_chunks"] == r["comm_chunks"]
+              and f["reverse"] == r["reverse"]
+              and f["scatter_axis"] == r["scatter_axis"]]
+        assert fp and r["comm_bytes"] < fp[0]["comm_bytes"], (s["seam"], r)
+assert wire["any_quantized_win"], \
+    "no seam shows an in-budget low-precision wire beating the fp wire"
+picks = {s["seam"]: (s["plan"]["mode"], s["plan"]["wire_dtype"])
+         for s in seams}
+print(f"BENCH_tuning.json wire sweep ok: {len(seams)} seams, "
+      f"budget={budget}, picks={picks}")
 EOF

@@ -22,7 +22,6 @@ from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
-from jax import core
 
 # v5e constants (task statement)
 PEAK_FLOPS = 197e12

@@ -20,7 +20,7 @@ that matches DMA sends to semaphore waits and builds a happens-before order
 1. **semaphore balance** — every remote-copy send/recv signal is matched by
    a wait and all semaphores balance by kernel exit (a stuck wait, an
    undrained send, or an unconsumed arrival is reported with its grid cell).
-2. **slot race freedom** — an ``a_agg``/scratch slot landing from a DMA is
+2. **slot race freedom** — an ``a_agg``/work-buffer slot landing from a DMA is
    never read or written without a happens-before edge through the arriving
    step's recv-semaphore wait, and no slot is written by two unordered DMAs
    (flagged with step/slot provenance).
@@ -85,8 +85,8 @@ def flux_tile_footprint(kind: str, bm: int, bk: int, bn: int, *,
     Mirrors the ``scratch_shapes`` of ``kernels/ag_gemm.py`` /
     ``kernels/gemm_rs.py`` exactly (the kernelcheck trace cross-checks the
     two stay in sync): fp32 accumulator + A/B input tiles + cast/stage
-    buffers + the optional bias tile.  HBM scratch (``a_agg``/``ws``) is
-    deliberately excluded — it is compiler-placed, not VMEM.
+    buffers + the optional bias tile.  The HBM work buffers (``a_agg``/
+    ``ws``, declared as extra outputs) are not VMEM and are excluded.
     """
     assert kind in ("ag", "rs"), kind
     ob = out_bytes or dtype_bytes
@@ -175,9 +175,17 @@ class Captured:
     grid: Tuple[int, ...]
     in_specs: Sequence
     out_specs: object
-    out_shape: jax.ShapeDtypeStruct
+    out_shape: object
     scratch_shapes: Sequence
     operands: Tuple
+
+    def outputs(self) -> List[Tuple[object, jax.ShapeDtypeStruct]]:
+        """(spec, shape) per output: the result first, then any HBM work
+        buffers a kernel declares as extra outputs (``A_agg``, in-flight
+        partials)."""
+        if isinstance(self.out_shape, (tuple, list)):
+            return list(zip(self.out_specs, self.out_shape))
+        return [(self.out_specs, self.out_shape)]
 
 
 @contextlib.contextmanager
@@ -291,8 +299,25 @@ class Event:
 
 
 class _Sem:
+    """A DMA semaphore, or an array of them (``sem.at[i, j]`` names one)."""
+
     def __init__(self, name: str):
         self.name = name
+
+    @property
+    def at(self):
+        return self
+
+    def __getitem__(self, idx):
+        idx = idx if isinstance(idx, tuple) else (idx,)
+        return _Sem(f"{self.name}[{','.join(str(_as_int(i)) for i in idx)}]")
+
+
+def _is_sem(entry) -> bool:
+    """A ``scratch_shapes`` entry that allocates DMA semaphore(s)."""
+    from repro import compat
+    return (entry is compat.DMA_SEM or isinstance(entry, type(compat.DMA_SEM))
+            or "semaphore" in str(getattr(entry, "memory_space", "")).lower())
 
 
 class _Ref:
@@ -531,7 +556,6 @@ def _spec_space(spec) -> str:
 def _build_static_args(cap: Captured, rec: _Recorder):
     """Shims for the non-blocked args (built once per rank): ANY/SMEM
     operands, the unblocked output, and every scratch entry."""
-    from repro import compat
 
     ins = []
     blocked_in: List[Tuple[int, object, object]] = []   # (argpos, spec, op)
@@ -544,14 +568,17 @@ def _build_static_args(cap: Captured, rec: _Recorder):
         else:
             ins.append(None)
             blocked_in.append((i, spec, op))
-    if cap.out_specs.block_shape is None:
-        out = _Ref("out", cap.out_shape.shape, cap.out_shape.dtype, "any",
+    (out_spec, out_shape), *work = cap.outputs()
+    if out_spec.block_shape is None:
+        out = _Ref("out", out_shape.shape, out_shape.dtype, "any",
                    rec, is_output=True)
     else:
         out = None
+    work = [_Ref(f"work{i}", shape.shape, shape.dtype, "any", rec)
+            for i, (_spec, shape) in enumerate(work)]
     scratch = []
     for i, entry in enumerate(cap.scratch_shapes):
-        if entry is compat.DMA_SEM or isinstance(entry, type(compat.DMA_SEM)):
+        if _is_sem(entry):
             scratch.append(_Sem(f"sem{i}"))
         else:
             space = str(getattr(entry, "memory_space", "vmem")).lower()
@@ -559,14 +586,15 @@ def _build_static_args(cap: Captured, rec: _Recorder):
                 "smem" if "smem" in space else "vmem")
             scratch.append(_Ref(f"scratch{i}", entry.shape, entry.dtype,
                                 space, rec))
-    return ins, blocked_in, out, scratch
+    return ins, blocked_in, out, work + scratch
 
 
 def _trace_rank(cap: Captured, label: str, rank: int) -> _Recorder:
     """Run the kernel body for every grid cell on one logical rank."""
-    rec = _Recorder(label, rank, cap.out_shape)
+    out_spec, out_shape = cap.outputs()[0]
+    rec = _Recorder(label, rank, out_shape)
     ins, blocked_in, out_static, scratch = _build_static_args(cap, rec)
-    out_blocked = cap.out_specs.block_shape is not None
+    out_blocked = out_spec.block_shape is not None
 
     with _patched_primitives(rec, cap.grid):
         for cell in itertools.product(*(range(g) for g in cap.grid)):
@@ -577,10 +605,10 @@ def _trace_rank(cap: Captured, label: str, rank: int) -> _Recorder:
                 args[pos] = _Ref(f"in{pos}", spec.block_shape, op.dtype,
                                  "vmem", rec)
             if out_blocked:
-                spec = cap.out_specs
+                spec = out_spec
                 idx = tuple(_as_int(i) for i in spec.index_map(*cell))
                 origin = tuple(b * i for b, i in zip(spec.block_shape, idx))
-                out = _Ref("out", spec.block_shape, cap.out_shape.dtype,
+                out = _Ref("out", spec.block_shape, out_shape.dtype,
                            "vmem", rec, is_output=True, block_origin=origin)
             else:
                 out = out_static
@@ -780,7 +808,7 @@ def _ring_errors(label: str, kind: str, n_dev: int, reverse: bool,
                             f"{e.where}: rs forwards in-flight slot "
                             f"{src_slot}->{dst_slot}; the decomposed ring "
                             f"expects {step}->{step + 1}")
-            elif e.kind == "read" and kind == "ag" and e.buf.startswith("scratch"):
+            elif e.kind == "read" and kind == "ag" and e.buf.startswith("work"):
                 slot = e.region.dims[0][0]
                 want = ag_owner[me][step]
                 if slot != want:
@@ -823,15 +851,13 @@ def _coverage_errors(label: str, recs: List[_Recorder]) -> List[str]:
 def traced_vmem_bytes(cap: Captured) -> int:
     """VMEM footprint of a captured call: VMEM scratch + 2x every blocked
     in/out block (Pallas double-buffers blocked refs across grid steps)."""
-    from repro import compat
     total = 0
     for entry in cap.scratch_shapes:
-        if entry is compat.DMA_SEM or isinstance(entry, type(compat.DMA_SEM)):
+        if _is_sem(entry):
             continue
         if "vmem" in str(getattr(entry, "memory_space", "vmem")).lower():
             total += int(np.prod(entry.shape)) * np.dtype(entry.dtype).itemsize
-    for spec, op in list(zip(cap.in_specs, cap.operands)) + [
-            (cap.out_specs, cap.out_shape)]:
+    for spec, op in list(zip(cap.in_specs, cap.operands)) + cap.outputs():
         if spec.block_shape is not None:
             total += 2 * int(np.prod(spec.block_shape)) * \
                 np.dtype(op.dtype).itemsize
@@ -839,7 +865,6 @@ def traced_vmem_bytes(cap: Captured) -> int:
 
 
 def _budget_errors(label: str, cap: Captured) -> List[str]:
-    from repro import compat
     errs = []
     vmem = traced_vmem_bytes(cap)
     if vmem > VMEM_LIMIT_BYTES:
@@ -852,7 +877,7 @@ def _budget_errors(label: str, cap: Captured) -> List[str]:
         if spec.block_shape is None and _spec_space(spec) == "smem":
             smem += op.size * np.dtype(op.dtype).itemsize
     for entry in cap.scratch_shapes:
-        if entry is compat.DMA_SEM or isinstance(entry, type(compat.DMA_SEM)):
+        if _is_sem(entry):
             continue
         if "smem" in str(getattr(entry, "memory_space", "")).lower():
             smem += int(np.prod(entry.shape)) * np.dtype(entry.dtype).itemsize
@@ -973,9 +998,14 @@ def _half_blocks(gm: int, gk: int, gn: int) -> Tuple[int, int, int]:
     """Blocks at half the cell dims: guarantees a multi-tile grid on every
     axis that can afford one, so the swizzle/accumulator logic is actually
     exercised (full-dim blocks would collapse the inner grid to 1x1x1)."""
-    from repro.kernels.ops import plan_blocks
-    return plan_blocks(gm, gk, gn, max(gm // 2, 1), max(gk // 2, 1),
-                       max(gn // 2, 1))
+    def half(d: int) -> int:
+        # the checked schedule holds for any dividing block, so these need
+        # not be (16, 128)-aligned like the blocks ``ops.plan_blocks`` runs
+        b = max(d // 2, 1)
+        while d % b:
+            b -= 1
+        return b
+    return half(gm), half(gk), half(gn)
 
 
 @register

@@ -4,8 +4,8 @@ Replaces the three ``grep -E`` gates that used to live in
 ``scripts/verify.sh`` (compat-import, private-backend, removed-wrapper)
 and adds two rules greps could not express without false positives:
 
-- ``compat-import``     backend-version-dependent JAX APIs (shard_map,
-                        CompilerParams, pallas tpu import, lax.axis_size)
+- ``compat-import``     drift-prone JAX APIs (shard_map, CompilerParams,
+                        pallas tpu import, lax.axis_size, ``jax.core.*``)
                         must route through ``repro.compat``.
 - ``private-backend``   ``repro.core.overlap``'s underscore backends are an
                         implementation detail; call ``FusedOp`` / the
@@ -86,7 +86,7 @@ _PRIVATE_BACKEND_RE = re.compile(
     r"^_(ag_matmul|matmul_ar|matmul_rs)_(xla|decomposed|bidir|flux|impl)")
 _REMOVED_WRAPPERS = {"ag_matmul", "matmul_rs", "matmul_ar"}
 _RAW_COLLECTIVES = {"ppermute", "all_gather", "all_to_all", "psum_scatter"}
-_COMPILER_PARAMS = {"TPUCompilerParams", "CompilerParams"}
+_COMPILER_PARAMS = {"CompilerParams"}
 _ESCAPE_RE = re.compile(r"#\s*lint:\s*allow\(([a-z0-9-]+(?:\s*,\s*[a-z0-9-]+)*)\)")
 
 
@@ -99,6 +99,10 @@ class Violation:
 
     def __str__(self):
         return f"{self.path}:{self.line}: [{self.rule}] {self.message}"
+
+
+def _is_jax_core(module: str) -> bool:
+    return module == "jax.core" or module.startswith("jax.core.")
 
 
 def _is_private_backend(name: str) -> bool:
@@ -179,22 +183,26 @@ class _Visitor(ast.NodeVisitor):
     # ---- imports ----------------------------------------------------------
     def visit_Import(self, node):
         for alias in node.names:
-            if alias.name.startswith("jax.experimental.shard_map"):
+            if alias.name.startswith("jax") and (
+                    alias.name.endswith(".shard_map")
+                    or _is_jax_core(alias.name)):
                 self._hit(node, "compat-import",
-                          "import jax.experimental.shard_map — use "
-                          "repro.compat.shard_map")
+                          f"import {alias.name} — use repro.compat")
         self.generic_visit(node)
 
     def visit_ImportFrom(self, node):
         mod = node.module or ""
         names = {a.name for a in node.names}
-        if mod == "jax.experimental.shard_map" or (
-                mod == "jax" and "shard_map" in names):
+        if mod.startswith("jax") and (mod.endswith(".shard_map")
+                                      or "shard_map" in names):
             rule = ("bare-shard-map" if mod == "jax"
                     else "compat-import")
             self._hit(node, rule,
                       f"shard_map imported from {mod!r} — use "
                       "repro.compat.shard_map")
+        if _is_jax_core(mod) or (mod == "jax" and "core" in names):
+            self._hit(node, "compat-import",
+                      f"jax.core imported from {mod!r} — use repro.compat")
         if mod.startswith("jax.experimental.pallas") and "tpu" in names:
             self._hit(node, "compat-import",
                       "pallas tpu backend import — use repro.compat.pltpu")
@@ -222,6 +230,10 @@ class _Visitor(ast.NodeVisitor):
             self._hit(node, "compat-import",
                       f"{node.attr} attribute — use "
                       "repro.compat.compiler_params")
+        if (node.attr == "core" and isinstance(base, ast.Name)
+                and base.id == "jax"):
+            self._hit(node, "compat-import",
+                      "jax.core — use repro.compat")
         if node.attr == "axis_size" and base_name == "lax":
             self._hit(node, "compat-import",
                       "lax.axis_size — use repro.compat.axis_size")

@@ -46,6 +46,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import compat
 from repro.core.overlap import SEAM_SCOPE_PREFIX
 
 # primitive names as they appear in traced jaxprs (``lax.psum_scatter``
@@ -204,7 +205,7 @@ def _taint_walk(jaxpr, tainted: set, completed: set, tp_axis: str,
     for eqn in jaxpr.eqns:
         prim = eqn.primitive.name
         in_vars = [v for v in eqn.invars if hasattr(v, "aval")
-                   and not isinstance(v, jax.core.Literal)]
+                   and not isinstance(v, compat.Literal)]
         t_in = [v for v in in_vars if v in tainted]
         if not t_in:
             # sub-jaxprs with no tainted inputs can still not introduce

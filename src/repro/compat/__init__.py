@@ -1,60 +1,46 @@
-"""JAX/Pallas portability layer — the ONLY module allowed to touch
-version-drifted JAX symbols.
+"""JAX/Pallas portability layer — the ONLY module allowed to touch JAX's
+drift-prone surface.
 
-The repo targets a range of JAX generations (see ``MIN_JAX`` /
-``MAX_TESTED_JAX``) whose public surface moved underneath us:
+The repo runs on the installed JAX (0.9.0) and spells each symbol the way
+that release does:
 
-  ===========================  ==============================  ==================
-  symbol (newest generation)   older generation                compat entry point
-  ===========================  ==============================  ==================
-  ``jax.shard_map``            ``jax.experimental.shard_map``  ``shard_map``
-  ``shard_map(check_vma=)``    ``shard_map(check_rep=)``       ``shard_map``
-  ``pltpu.CompilerParams``     ``pltpu.TPUCompilerParams``     ``pallas_compiler_params``
-  ``lax.axis_size``            ``lax.psum(1, axis)``           ``axis_size``
-  ``pl.ANY(shape, dtype)``     ``pltpu.ANY(shape, dtype)``     ``hbm_scratch``
-  ===========================  ==============================  ==================
+  ==============================  ==============================
+  JAX symbol                      compat entry point
+  ==============================  ==============================
+  ``jax.shard_map(check_vma=)``   ``shard_map``
+  ``pltpu.CompilerParams``        ``pallas_compiler_params``
+  ``lax.axis_size``               ``axis_size``
+  ``jax.extend.core.Literal``     ``Literal``
+  ==============================  ==============================
 
 Everything else (``pl.BlockSpec``, ``pl.when``, ``pl.ds``, ``lax``
-collectives, ...) has been stable across the supported range and is imported
-directly by consumers.
+collectives, ...) is imported directly by consumers.
 
-Rule (enforced by ``tests/test_compat.py``): no module outside
-``repro/compat/`` may reference ``jax.shard_map``,
-``jax.experimental.shard_map``, or ``pltpu.*CompilerParams`` directly —
+Rule (enforced by ``repro.analysis.lint``, rule ``compat-import``): no
+module outside ``repro/compat/`` may reference ``jax.shard_map``,
+``jax.core``, ``lax.axis_size`` or ``pltpu.*CompilerParams`` directly —
 import through this package instead.
 """
-import jax as _jax
+from jax.extend.core import Literal
 
-from repro.compat._version import (JAX_VERSION, MAX_TESTED_JAX, MIN_JAX,
-                                   jax_at_least, version_summary)
-
-# Normalize RNG semantics across generations: newer JAX defaults
-# ``jax_threefry_partitionable=True`` (random bits independent of how the
-# computation is sharded).  Older releases default to False, where
-# ``jax.random.*`` inside a jit with sharded outputs produces DIFFERENT
-# values than the same call unsharded — breaking cross-layout determinism
-# (same seed, different init at dp=2).  Opt in to the new semantics
-# everywhere so parameter initialization is layout-invariant.
-if hasattr(_jax.config, "jax_threefry_partitionable"):
-    _jax.config.update("jax_threefry_partitionable", True)
+from repro.compat._version import JAX_VERSION, version_summary
 from repro.compat._aot import cost_analysis
-from repro.compat._sharding import axis_size, shard_map, sharded_init
+from repro.compat._sharding import axis_size, shard_map
 from repro.compat._pallas import (ANY, DMA_SEM, SMEM, VMEM,
                                   LOGICAL_DEVICE_ID, SemaphoreType,
-                                  cost_estimate,
-                                  fused_collective_kernels_composable,
-                                  hbm_scratch, interpret_default,
+                                  barrier_semaphore, cost_estimate,
+                                  interpret_default,
                                   make_async_copy, make_async_remote_copy,
-                                  pallas_call, pallas_compiler_params)
+                                  pallas_call, pallas_compiler_params,
+                                  semaphore_signal, semaphore_wait)
 
 __all__ = [
-    "JAX_VERSION", "MIN_JAX", "MAX_TESTED_JAX", "jax_at_least",
-    "version_summary",
-    "shard_map", "axis_size", "sharded_init",
+    "JAX_VERSION", "version_summary", "Literal",
+    "shard_map", "axis_size",
     "pallas_call", "pallas_compiler_params", "interpret_default",
     "cost_estimate", "cost_analysis",
-    "fused_collective_kernels_composable",
-    "VMEM", "SMEM", "ANY", "hbm_scratch",
+    "VMEM", "SMEM", "ANY",
     "SemaphoreType", "DMA_SEM",
     "make_async_copy", "make_async_remote_copy", "LOGICAL_DEVICE_ID",
+    "barrier_semaphore", "semaphore_signal", "semaphore_wait",
 ]

@@ -1,10 +1,4 @@
-"""AOT-compilation (``jit(...).lower().compile()``) result helpers.
-
-``compiled.cost_analysis()`` drifted across JAX generations: newer releases
-return a flat ``dict`` of metrics, 0.4.x returns a one-element ``list`` of
-dicts (one per partition program).  Normalize to a dict so callers can
-``.get(...)`` regardless of generation.
-"""
+"""AOT-compilation (``jit(...).lower().compile()``) result helpers."""
 from __future__ import annotations
 
 from typing import Any, Dict
@@ -12,7 +6,4 @@ from typing import Any, Dict
 
 def cost_analysis(compiled) -> Dict[str, Any]:
     """Flat metrics dict from a compiled executable, or {} if unavailable."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if ca else {}
+    return dict(compiled.cost_analysis() or {})

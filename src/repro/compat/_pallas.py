@@ -1,16 +1,10 @@
 """Pallas TPU portability: compiler params, memory spaces, DMA helpers.
 
-Drift handled here:
-  - ``pltpu.TPUCompilerParams`` (0.4.x) was renamed ``pltpu.CompilerParams``;
-    field sets also differ between generations, so
-    ``pallas_compiler_params`` filters kwargs to what the installed class
-    accepts instead of exploding on a newer-generation knob.
-  - HBM ("ANY"-space) scratch buffers: callable ``pl.ANY(shape, dtype)`` on
-    newer JAX, only ``pltpu.ANY(shape, dtype)`` on 0.4.x
-    (``pl.ANY`` there is a plain enum member and not callable).
-  - ``interpret=`` defaults: CPU CI machines have no Mosaic toolchain, so
-    every kernel defaults to interpret mode unless a real TPU backend is
-    present; ``REPRO_PALLAS_INTERPRET`` overrides in both directions.
+- ``pallas_compiler_params`` filters kwargs to the fields
+  ``pltpu.CompilerParams`` accepts instead of exploding on an unknown knob.
+- ``interpret=`` defaults: the CPU backend has no Mosaic toolchain, so every
+  kernel defaults to interpret mode unless a TPU backend is present;
+  ``REPRO_PALLAS_INTERPRET`` overrides in both directions.
 """
 from __future__ import annotations
 
@@ -27,18 +21,16 @@ from jax.experimental.pallas import tpu as pltpu
 # --------------------------------------------------------------------------
 # compiler params
 # --------------------------------------------------------------------------
-_COMPILER_PARAMS_CLS = (getattr(pltpu, "CompilerParams", None)
-                        or getattr(pltpu, "TPUCompilerParams"))
+_COMPILER_PARAMS_CLS = pltpu.CompilerParams
 _CP_FIELDS = {f.name for f in dataclasses.fields(_COMPILER_PARAMS_CLS)}
 
 
 def pallas_compiler_params(**kwargs):
-    """Build the installed generation's TPU compiler-params object.
+    """Build the TPU compiler-params object.
 
-    Accepts the union of knobs across generations
-    (``dimension_semantics``, ``collective_id``, ``vmem_limit_bytes``, ...)
-    and drops — with a warning — any the installed class does not know, so
-    kernels can be written once against the newest surface.
+    Knobs (``dimension_semantics``, ``collective_id``,
+    ``vmem_limit_bytes``, ...) the class does not know are dropped with a
+    warning.
     """
     kept = {k: v for k, v in kwargs.items() if k in _CP_FIELDS}
     dropped = sorted(set(kwargs) - set(kept))
@@ -61,38 +53,18 @@ def interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def fused_collective_kernels_composable() -> bool:
-    """Can several remote-DMA (ring) Pallas kernels share one jitted program?
-
-    On real TPUs (Mosaic lowering): always.  In interpret mode on older JAX,
-    ``make_async_remote_copy`` discharges into ``all_gather``/``argmax``
-    collectives nested inside the kernel's ``pl.when`` conditionals; XLA
-    CPU's sharding propagation then hard-crashes (``Array::Reshape`` check
-    failure, observed on jax 0.4.37) once certain pairs of such kernels
-    appear in the same program — a single kernel per program compiles and
-    runs correctly.  Callers composing fused kernels (e.g. the flux overlap
-    seams) must fall back to a collective-equivalent path when this returns
-    False.
-    """
-    from repro.compat._version import jax_at_least
-    if not interpret_default():
-        return True
-    return jax_at_least(0, 6)
-
-
 _PALLAS_CALL_PARAMS = frozenset(inspect.signature(pl.pallas_call).parameters)
 
 
 def pallas_call(kernel: Callable, *, interpret: Optional[bool] = None,
                 compiler_params: Any = None, **kwargs):
-    """``pl.pallas_call`` with version-portable defaults.
+    """``pl.pallas_call`` with portable defaults.
 
     - ``interpret=None`` resolves via :func:`interpret_default` so every
       kernel runs on CPU CI without each call site re-implementing the probe.
     - ``compiler_params`` may be a plain dict of knobs; it is routed through
       :func:`pallas_compiler_params` to the installed params class.
-    - kwargs the installed ``pl.pallas_call`` does not know (e.g.
-      ``cost_estimate`` on very old releases) are dropped with a warning
+    - kwargs ``pl.pallas_call`` does not know are dropped with a warning
       rather than raising.
     """
     if interpret is None:
@@ -113,39 +85,21 @@ def pallas_call(kernel: Callable, *, interpret: Optional[bool] = None,
 
 def cost_estimate(*, flops: int, bytes_accessed: int,
                   transcendentals: int = 0):
-    """Portable ``pl.CostEstimate`` (None when the release predates it)."""
-    ce_cls = getattr(pl, "CostEstimate", None)
-    if ce_cls is None:
-        return None
-    return ce_cls(flops=flops, bytes_accessed=bytes_accessed,
-                  transcendentals=transcendentals)
+    """``pl.CostEstimate`` for a kernel's scheduler hint."""
+    return pl.CostEstimate(flops=flops, bytes_accessed=bytes_accessed,
+                           transcendentals=transcendentals)
 
 
 # --------------------------------------------------------------------------
 # memory spaces & scratch shapes
 # --------------------------------------------------------------------------
-#: VMEM scratch allocator: ``VMEM(shape, dtype)`` (stable across generations).
+#: VMEM scratch allocator: ``VMEM(shape, dtype)``.
 VMEM = pltpu.VMEM
 #: SMEM memory space (BlockSpec ``memory_space=`` and scratch allocator).
 SMEM = pltpu.SMEM
 #: "ANY" (compiler-placed / HBM) memory space for ``pl.BlockSpec``.
-ANY = getattr(pl, "ANY", None)
-if ANY is None:                                      # pragma: no cover
-    ANY = pltpu.ANY
+ANY = pl.ANY
 
-
-def hbm_scratch(shape: tuple, dtype):
-    """HBM-resident scratch buffer spec (``scratch_shapes=`` entry).
-
-    Newer JAX spells this ``pl.ANY(shape, dtype)``; on 0.4.x only the TPU
-    enum ``pltpu.ANY`` is callable.
-    """
-    for space in (getattr(pltpu, "ANY", None), getattr(pl, "ANY", None)):
-        if callable(space):
-            return space(shape, dtype)
-    raise NotImplementedError(
-        "no callable ANY/HBM memory space on this JAX; cannot allocate "
-        "HBM scratch for fused collective kernels")
 
 
 # --------------------------------------------------------------------------
@@ -165,5 +119,9 @@ SemaphoreType = _require("SemaphoreType")
 DMA_SEM = SemaphoreType.DMA
 make_async_copy = _require("make_async_copy")
 make_async_remote_copy = _require("make_async_remote_copy")
+#: the per-``collective_id`` cross-device barrier semaphore of a kernel.
+barrier_semaphore = _require("get_barrier_semaphore")
+semaphore_signal = _require("semaphore_signal")
+semaphore_wait = _require("semaphore_wait")
 #: ``device_id_type=`` value for logical (mesh-coordinate) addressing.
 LOGICAL_DEVICE_ID = _require("DeviceIdType").LOGICAL

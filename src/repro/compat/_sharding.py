@@ -1,80 +1,23 @@
-"""``shard_map`` and mesh-axis helpers across JAX generations.
-
-Newest JAX exposes ``jax.shard_map`` with a ``check_vma`` kwarg; the
-generation this repo pins in CI (0.4.x) ships it as
-``jax.experimental.shard_map.shard_map`` with the same knob named
-``check_rep``.  ``compat.shard_map`` accepts either spelling and forwards to
-whichever implementation is installed.
-"""
+"""``shard_map`` and mesh-axis helpers."""
 from __future__ import annotations
 
-from typing import Any, Callable, Optional
+from typing import Callable, Optional
 
 import jax
-from jax import lax  # noqa: F401  (axis_size fallback)
-
-from repro.compat._version import assumed_floor
-
-if hasattr(jax, "shard_map") and not assumed_floor():   # jax >= 0.6
-    _shard_map_impl: Callable = jax.shard_map
-    _CHECK_KWARG = "check_vma"
-else:                                               # jax 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _CHECK_KWARG = "check_rep"
+from jax import lax
 
 
 def shard_map(f: Callable, *, mesh, in_specs, out_specs,
-              check_vma: Optional[bool] = None,
-              check_rep: Optional[bool] = None,
-              auto: Any = None) -> Callable:
-    """Version-portable ``shard_map``.
-
-    ``check_vma`` (new spelling) and ``check_rep`` (old spelling) are
-    interchangeable; pass at most one.  ``auto`` is forwarded only when
-    given, so each generation keeps its own default.
-    """
-    if check_vma is not None and check_rep is not None:
-        raise TypeError("pass either check_vma or check_rep, not both")
-    check = check_vma if check_vma is not None else check_rep
-    kwargs = {}
-    if check is not None:
-        kwargs[_CHECK_KWARG] = check
-    if auto is not None:
-        kwargs["auto"] = auto
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
-
-
-def sharded_init(fn: Callable, shardings) -> Callable:
-    """Run an RNG-based initializer and place the results per ``shardings``,
-    with layout-invariant values.
-
-    Jitting an initializer with ``out_shardings`` looks equivalent but is
-    NOT on the 0.4.x generation: the SPMD partitioner miscompiles
-    partitionable-threefry bits flowing into ``concatenate`` on a >=2-D
-    mesh (observed on jax 0.4.37, CPU, (2, 2) mesh: the packed QKV weights
-    differ from every 1-D mesh and from the eager run — same PRNG key).
-    Computing unsharded and resharding via ``device_put`` keeps the RNG out
-    of the partitioner, so the same seed yields the same parameters on every
-    mesh layout.  Cost: the full tree is materialized unsharded before the
-    reshard — fine for tests/CPU; revisit (per-leaf init or a fixed JAX)
-    before very-large-scale runs.
-    """
-    def run(*args, **kwargs):
-        out = jax.jit(fn)(*args, **kwargs)
-        return jax.device_put(out, shardings)
-    return run
+              check_vma: Optional[bool] = None) -> Callable:
+    """``jax.shard_map``; ``check_vma`` is forwarded only when given."""
+    kwargs = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def axis_size(axis_name) -> int:
     """Size of a mapped mesh axis, callable inside ``shard_map``.
-
-    ``None`` means "not parallelized" and returns 1.  Older JAX has no
-    ``lax.axis_size``; there ``lax.psum(1, axis)`` is the canonical spelling
-    and returns a static int for a constant operand.
-    """
+    ``None`` means "not parallelized" and returns 1."""
     if axis_name is None:
         return 1
-    if hasattr(lax, "axis_size") and not assumed_floor():
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    return lax.axis_size(axis_name)

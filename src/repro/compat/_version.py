@@ -1,21 +1,12 @@
-"""Installed-JAX version detection for the portability layer.
+"""Installed-JAX version, for logs and error messages.
 
-``REPRO_COMPAT_ASSUME_JAX=<version>`` caps the detected version (never
-raises it): the ``--jax-min`` CI lane sets it to the 0.4.30 floor so the
-compat contract tests exercise the OLDEST-generation code paths (psum
-axis-size spelling, no fused-collective composition, old compiler-params
-fields) on whatever JAX the container actually ships.
+The repo runs on the JAX the environment installs (0.9.0, with libtpu
+0.0.34 on the chip); the portability layer spells each symbol the way that
+release does and supports no other.
 """
 from __future__ import annotations
 
-import os
-
 import jax
-
-#: Oldest JAX generation the shim is written against.
-MIN_JAX = (0, 4, 30)
-#: Newest JAX the shim has been exercised on (CI pin).
-MAX_TESTED_JAX = (0, 4, 37)
 
 
 def _parse(version: str) -> tuple:
@@ -28,30 +19,9 @@ def _parse(version: str) -> tuple:
     return tuple(parts)
 
 
-_INSTALLED = _parse(jax.__version__)
-_ASSUMED = os.environ.get("REPRO_COMPAT_ASSUME_JAX")
-
-JAX_VERSION = (min(_INSTALLED, _parse(_ASSUMED)) if _ASSUMED
-               else _INSTALLED)
-
-
-def assumed_floor() -> bool:
-    """True when ``REPRO_COMPAT_ASSUME_JAX`` downgrades the detected
-    version — feature-probed newer spellings must then be IGNORED so the
-    floor-generation code paths actually run."""
-    return JAX_VERSION < _INSTALLED
-
-
-def jax_at_least(*version: int) -> bool:
-    """True when the (possibly capped) JAX is at least ``version``."""
-    return JAX_VERSION >= tuple(version)
+JAX_VERSION = _parse(jax.__version__)
 
 
 def version_summary() -> str:
     """One-line provenance string for logs and error messages."""
-    lo = ".".join(map(str, MIN_JAX))
-    hi = ".".join(map(str, MAX_TESTED_JAX))
-    assumed = (f"; assumed {'.'.join(map(str, JAX_VERSION))} via "
-               f"REPRO_COMPAT_ASSUME_JAX" if assumed_floor() else "")
-    return (f"jax {jax.__version__}{assumed} (compat range: {lo} .. {hi}; "
-            f"newer releases resolved best-effort)")
+    return f"jax {jax.__version__}"

@@ -596,8 +596,6 @@ def _rs_core(ys: Tuple[Array, ...], ws: Tuple[Array, ...], axis, mode: str,
             p = jnp.einsum("...sf,fd->...sd", y, w)
             acc = p if acc is None else acc + p
         return acc
-    if mode == "flux" and not _flux_available():
-        mode = "decomposed"
     if mode == "xla":
         acc = None
         for y, w in zip(ys, ws):
@@ -934,15 +932,6 @@ def _a2a_bwd(op: FusedOp, res, g):
 # ---------------------------------------------------------------------------
 # mode="flux": fused Pallas kernels (see repro/kernels/)
 # ---------------------------------------------------------------------------
-def _flux_available() -> bool:
-    """Flux seams compose several remote-DMA kernels into one jitted program
-    (fwd AG + bwd RS, or both MLP seams); on JAX generations where the
-    interpret-mode DMA discharge cannot compose (see
-    ``compat.fused_collective_kernels_composable``) fall back to the
-    decomposed ring — same numerics, ``ppermute``-based."""
-    return compat.fused_collective_kernels_composable()
-
-
 def _blocks_kw(blocks) -> dict:
     if blocks is None:
         return {}
@@ -1126,9 +1115,7 @@ def _fused_ag(op: FusedOp, x, ws, bias, scale, residual):
         return _apply_epilogue(op, ys, bias, scale, residual)
 
     if mode == "flux":
-        if _flux_available():
-            return _fused_ag_flux(op, x, ws, bias, scale, residual)
-        mode = "decomposed"
+        return _fused_ag_flux(op, x, ws, bias, scale, residual)
 
     if mode == "xla":
         full = _gather_full(x, op.axis, op.wire_dtype)

@@ -42,17 +42,32 @@ from repro import compat
 from repro.core.overlap import ACTIVATIONS as EPILOGUE_ACTS
 
 
+def ring_barrier(axis_name: str, n_dev: int) -> None:
+    """Handshake with both ring neighbours before the first remote DMA: a
+    device may write into a neighbour's scratch only once that neighbour
+    has entered the kernel.  Mosaic only: JAX's generic interpreter cannot
+    lower a barrier semaphore, and it turns each remote DMA into
+    collectives that already synchronize every device."""
+    me = lax.axis_index(axis_name)
+    sem = compat.barrier_semaphore()
+    for hop in (1, n_dev - 1):
+        compat.semaphore_signal(sem, device_id=lax.rem(me + hop, n_dev),
+                                device_id_type=compat.LOGICAL_DEVICE_ID)
+    compat.semaphore_wait(sem, 2)
+
+
 def _ag_gemm_kernel(a_ref, b_ref, *rest,           # HBM: [M_sh,K], [K,N], [n*M_sh,N]
                     axis_name: str, n_dev: int, reverse: bool,
                     bm: int, bk: int, bn: int,
-                    activation=None, has_bias: bool = False):
+                    activation=None, has_bias: bool = False,
+                    barrier: bool = False):
     if has_bias:
         (bias_ref, o_ref, a_agg, acc_ref, a_vmem, b_vmem, o_vmem, bias_vmem,
-         local_sem, send_sem, recv_sem, copy_a, copy_b, copy_o) = rest
+         local_sem, send_sem, recv_sems, copy_a, copy_b, copy_o) = rest
     else:
         bias_ref = bias_vmem = None
         (o_ref, a_agg, acc_ref, a_vmem, b_vmem, o_vmem,
-         local_sem, send_sem, recv_sem, copy_a, copy_b, copy_o) = rest
+         local_sem, send_sem, recv_sems, copy_a, copy_b, copy_o) = rest
     step = pl.program_id(0)
     mi = pl.program_id(1)
     ni = pl.program_id(2)
@@ -69,6 +84,8 @@ def _ag_gemm_kernel(a_ref, b_ref, *rest,           # HBM: [M_sh,K], [K,N], [n*M_
     # ---- step 0 bootstrap: stage the local shard into its A_agg slot -------
     @pl.when((step == 0) & first_inner)
     def _preset_local():
+        if barrier:
+            ring_barrier(axis_name, n_dev)
         cp = compat.make_async_copy(a_ref, a_agg.at[me], local_sem)
         cp.start()
         cp.wait()
@@ -82,7 +99,7 @@ def _ag_gemm_kernel(a_ref, b_ref, *rest,           # HBM: [M_sh,K], [K,N], [n*M_
             # upstream neighbor during its previous step.
             compat.make_async_remote_copy(
                 src_ref=a_agg.at[owner], dst_ref=a_agg.at[owner],
-                send_sem=send_sem, recv_sem=recv_sem,
+                send_sem=send_sem, recv_sem=recv_sems.at[owner],
                 device_id=nbr, device_id_type=compat.LOGICAL_DEVICE_ID,
             ).wait_recv()
 
@@ -90,7 +107,7 @@ def _ag_gemm_kernel(a_ref, b_ref, *rest,           # HBM: [M_sh,K], [K,N], [n*M_
         def _forward():
             compat.make_async_remote_copy(
                 src_ref=a_agg.at[owner], dst_ref=a_agg.at[owner],
-                send_sem=send_sem, recv_sem=recv_sem,
+                send_sem=send_sem, recv_sem=recv_sems.at[owner],
                 device_id=nbr, device_id_type=compat.LOGICAL_DEVICE_ID,
             ).start()
 
@@ -132,7 +149,7 @@ def _ag_gemm_kernel(a_ref, b_ref, *rest,           # HBM: [M_sh,K], [K,N], [n*M_
     def _drain_send():
         compat.make_async_remote_copy(
             src_ref=a_agg.at[owner], dst_ref=a_agg.at[owner],
-            send_sem=send_sem, recv_sem=recv_sem,
+            send_sem=send_sem, recv_sem=recv_sems.at[owner],
             device_id=nbr, device_id_type=compat.LOGICAL_DEVICE_ID,
         ).wait_send()
 
@@ -150,6 +167,7 @@ def ag_gemm(a_shard: jax.Array, b_local: jax.Array, *, axis_name: str,
     k2, n = b_local.shape
     assert k == k2
     assert activation is None or activation in EPILOGUE_ACTS, activation
+    interpret = compat.interpret_default() if interpret is None else interpret
     out_dtype = out_dtype or a_shard.dtype
     bm, bk, bn = min(bm, m_sh), min(bk, k), min(bn, n)
     assert m_sh % bm == 0 and k % bk == 0 and n % bn == 0, (
@@ -158,12 +176,12 @@ def ag_gemm(a_shard: jax.Array, b_local: jax.Array, *, axis_name: str,
     has_bias = bias is not None
     kernel = functools.partial(
         _ag_gemm_kernel, axis_name=axis_name, n_dev=n_dev, reverse=reverse,
-        bm=bm, bk=bk, bn=bn, activation=activation, has_bias=has_bias)
+        bm=bm, bk=bk, bn=bn, activation=activation, has_bias=has_bias,
+        barrier=not interpret)
     in_specs = [pl.BlockSpec(memory_space=compat.ANY),
                 pl.BlockSpec(memory_space=compat.ANY)]
     operands = [a_shard, b_local]
     scratch = [
-        compat.hbm_scratch((n_dev, m_sh, k), a_shard.dtype),   # A_agg (HBM)
         compat.VMEM((bm, bn), jnp.float32),          # accumulator
         compat.VMEM((bm, bk), a_shard.dtype),
         compat.VMEM((bk, bn), b_local.dtype),
@@ -176,16 +194,23 @@ def ag_gemm(a_shard: jax.Array, b_local: jax.Array, *, axis_name: str,
         scratch.append(compat.VMEM((1, bn), bias.dtype))       # bias tile
     scratch += [
         compat.DMA_SEM, compat.DMA_SEM,
-        compat.DMA_SEM, compat.DMA_SEM,
-        compat.DMA_SEM, compat.DMA_SEM,
+        # one arrival semaphore per A_agg slot: a wait is satisfied only by
+        # the shard it waits for, even if the upstream rank runs a step
+        # ahead and a later shard lands first
+        compat.SemaphoreType.DMA((n_dev,)),
+        compat.DMA_SEM, compat.DMA_SEM, compat.DMA_SEM,
     ]
+    # A_agg is a second output, dropped here: Mosaic allocates scratch only
+    # in VMEM, SMEM and semaphores, and the gathered shards need HBM
     return compat.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(memory_space=compat.ANY),
-        out_shape=jax.ShapeDtypeStruct((n_dev * m_sh, n), out_dtype),
+        out_specs=(pl.BlockSpec(memory_space=compat.ANY),) * 2,
+        out_shape=(jax.ShapeDtypeStruct((n_dev * m_sh, n), out_dtype),
+                   jax.ShapeDtypeStruct((n_dev, m_sh, k), a_shard.dtype)),
         scratch_shapes=scratch,
-        compiler_params=compat.pallas_compiler_params(collective_id=collective_id),
+        compiler_params=compat.pallas_compiler_params(
+            collective_id=None if interpret else collective_id),
         interpret=interpret,
-    )(*operands)
+    )(*operands)[0]

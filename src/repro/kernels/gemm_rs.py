@@ -27,23 +27,24 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from repro import compat
-from repro.kernels.ag_gemm import EPILOGUE_ACTS
+from repro.kernels.ag_gemm import EPILOGUE_ACTS, ring_barrier
 
 
 def _gemm_rs_kernel(a_ref, b_ref, *rest,           # HBM: [M,K_sh], [K_sh,N], [M/n,N]
                     axis_name: str, n_dev: int, reverse: bool,
                     bm: int, bk: int, bn: int,
-                    activation=None, has_bias: bool = False):
+                    activation=None, has_bias: bool = False,
+                    barrier: bool = False):
     # epilogue hook: bias/activation fold into the FINAL reduction step's
     # tile emit (after all n partials have summed — adding earlier would
     # apply the bias once per rank).
     if has_bias:
         (bias_ref, o_ref, ws, acc_ref, a_vmem, b_vmem, stage, o_stage,
-         bias_vmem, send_sem, recv_sem, copy_a, copy_b, copy_o) = rest
+         bias_vmem, send_sem, recv_sems, copy_a, copy_b, copy_o) = rest
     else:
         bias_ref = bias_vmem = None
         (o_ref, ws, acc_ref, a_vmem, b_vmem, stage, o_stage,
-         send_sem, recv_sem, copy_a, copy_b, copy_o) = rest
+         send_sem, recv_sems, copy_a, copy_b, copy_o) = rest
     step = pl.program_id(0)
     mi = pl.program_id(1)
     ni = pl.program_id(2)
@@ -56,6 +57,11 @@ def _gemm_rs_kernel(a_ref, b_ref, *rest,           # HBM: [M,K_sh], [K_sh,N], [M
     # swizzle: owner of the partial we compute at this step
     owner = lax.rem(me + sgn * (n_dev - 1 - step) + 2 * n_dev, n_dev)
     m_sh = n_m * bm
+
+    if barrier:
+        @pl.when((step == 0) & (mi == 0) & (ni == 0) & (ki == 0))
+        def _handshake():
+            ring_barrier(axis_name, n_dev)
 
     # ---- contraction: accumulate A[owner rows] @ B for this tile ------------
     ca = compat.make_async_copy(
@@ -82,7 +88,7 @@ def _gemm_rs_kernel(a_ref, b_ref, *rest,           # HBM: [M,K_sh], [K_sh,N], [M
             compat.make_async_remote_copy(
                 src_ref=ws.at[step, pl.ds(mi * bm, bm), pl.ds(ni * bn, bn)],
                 dst_ref=ws.at[step, pl.ds(mi * bm, bm), pl.ds(ni * bn, bn)],
-                send_sem=send_sem, recv_sem=recv_sem,
+                send_sem=send_sem, recv_sem=recv_sems.at[step, mi, ni],
                 device_id=nbr, device_id_type=compat.LOGICAL_DEVICE_ID,
             ).wait_recv()
             inc = compat.make_async_copy(
@@ -101,7 +107,7 @@ def _gemm_rs_kernel(a_ref, b_ref, *rest,           # HBM: [M,K_sh], [K_sh,N], [M
             compat.make_async_remote_copy(
                 src_ref=ws.at[step, pl.ds(mi * bm, bm), pl.ds(ni * bn, bn)],
                 dst_ref=ws.at[step + 1, pl.ds(mi * bm, bm), pl.ds(ni * bn, bn)],
-                send_sem=send_sem, recv_sem=recv_sem,
+                send_sem=send_sem, recv_sem=recv_sems.at[step + 1, mi, ni],
                 device_id=nbr, device_id_type=compat.LOGICAL_DEVICE_ID,
             ).start()
 
@@ -129,7 +135,7 @@ def _gemm_rs_kernel(a_ref, b_ref, *rest,           # HBM: [M,K_sh], [K_sh,N], [M
             compat.make_async_remote_copy(
                 src_ref=ws.at[step - 1, pl.ds(mi * bm, bm), pl.ds(ni * bn, bn)],
                 dst_ref=ws.at[step, pl.ds(mi * bm, bm), pl.ds(ni * bn, bn)],
-                send_sem=send_sem, recv_sem=recv_sem,
+                send_sem=send_sem, recv_sem=recv_sems.at[step, mi, ni],
                 device_id=nbr, device_id_type=compat.LOGICAL_DEVICE_ID,
             ).wait_send()
 
@@ -148,6 +154,7 @@ def gemm_rs(a_local: jax.Array, b_local: jax.Array, *, axis_name: str,
     assert k_sh == k2
     assert m % n_dev == 0, (m, n_dev)
     assert activation is None or activation in EPILOGUE_ACTS, activation
+    interpret = compat.interpret_default() if interpret is None else interpret
     m_sh = m // n_dev
     out_dtype = out_dtype or a_local.dtype
     partial_dtype = partial_dtype or out_dtype
@@ -158,12 +165,12 @@ def gemm_rs(a_local: jax.Array, b_local: jax.Array, *, axis_name: str,
     has_bias = bias is not None
     kernel = functools.partial(
         _gemm_rs_kernel, axis_name=axis_name, n_dev=n_dev, reverse=reverse,
-        bm=bm, bk=bk, bn=bn, activation=activation, has_bias=has_bias)
+        bm=bm, bk=bk, bn=bn, activation=activation, has_bias=has_bias,
+        barrier=not interpret)
     in_specs = [pl.BlockSpec(memory_space=compat.ANY),
                 pl.BlockSpec(memory_space=compat.ANY)]
     operands = [a_local, b_local]
     scratch = [
-        compat.hbm_scratch((n_dev, m_sh, n), partial_dtype),    # in-flight partials
         compat.VMEM((bm, bn), jnp.float32),          # accumulator
         compat.VMEM((bm, bk), a_local.dtype),
         compat.VMEM((bk, bn), b_local.dtype),
@@ -176,17 +183,23 @@ def gemm_rs(a_local: jax.Array, b_local: jax.Array, *, axis_name: str,
         operands.append(bias.reshape(1, n))
         scratch.append(compat.VMEM((1, bn), bias.dtype))        # bias tile
     scratch += [
-        compat.DMA_SEM, compat.DMA_SEM,
-        compat.DMA_SEM, compat.DMA_SEM,
         compat.DMA_SEM,
+        # one arrival semaphore per in-flight tile: a wait is satisfied only
+        # by the tile it waits for, whatever order the upstream's tiles land
+        compat.SemaphoreType.DMA((n_dev, m_sh // bm, n // bn)),
+        compat.DMA_SEM, compat.DMA_SEM, compat.DMA_SEM,
     ]
+    # the in-flight partials are a second output, dropped here: Mosaic
+    # allocates scratch only in VMEM, SMEM and semaphores
     return compat.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(memory_space=compat.ANY),
-        out_shape=jax.ShapeDtypeStruct((m_sh, n), out_dtype),
+        out_specs=(pl.BlockSpec(memory_space=compat.ANY),) * 2,
+        out_shape=(jax.ShapeDtypeStruct((m_sh, n), out_dtype),
+                   jax.ShapeDtypeStruct((n_dev, m_sh, n), partial_dtype)),
         scratch_shapes=scratch,
-        compiler_params=compat.pallas_compiler_params(collective_id=collective_id),
+        compiler_params=compat.pallas_compiler_params(
+            collective_id=None if interpret else collective_id),
         interpret=interpret,
-    )(*operands)
+    )(*operands)[0]

@@ -11,6 +11,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from repro.configs.base import ParallelConfig, get_config, get_smoke_config
+from repro.launch.cache import enable_compilation_cache
 from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.runtime.server import Request, ServeConfig, Server
@@ -50,6 +51,7 @@ def main() -> None:
                     help="chunked-prefill rows per dispatch (bounds how "
                          "long a long prompt stalls running decodes)")
     args = ap.parse_args()
+    enable_compilation_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     par = ParallelConfig(tp=args.tp, dp=args.dp, overlap_mode=args.mode,

@@ -14,6 +14,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from repro.configs.base import ParallelConfig, get_config, get_smoke_config
+from repro.launch.cache import enable_compilation_cache
 from repro.launch.mesh import make_mesh
 from repro.optim.adamw import AdamWConfig
 from repro.runtime import trainer as T
@@ -66,6 +67,7 @@ def main() -> None:
     ap.add_argument("--zero3", action="store_true")
     ap.add_argument("--grad-compress", action="store_true")
     args = ap.parse_args()
+    enable_compilation_cache()
 
     logging.basicConfig(level=logging.INFO)
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)

@@ -212,8 +212,9 @@ def init_model(key, cfg: ModelConfig, par: ParallelConfig,
                     "ffn": _init_ffn(kf, ffn_kind, cfg, ep, tp, dtype,
                                      par.fuse_w13)}
 
-        trees = [one(i) for i in range(reps)]
-        return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+        # vmapped over the repetition index: the same values as stacking
+        # per-layer trees, without holding every layer twice
+        return jax.vmap(one)(jnp.arange(reps))
 
     params["periods"] = [stack_init(i, kp) for i, kp in enumerate(period)]
 

@@ -227,7 +227,7 @@ def _block_decode(kind_pair, lp: Dict, lc: Dict, x: Array, pos, ctx, cfg,
 
 def decode_step(params: Dict, caches: Dict, tokens: Array, pos,
                 ctx: TPContext, cfg: ModelConfig, par: ParallelConfig,
-                block_tables=None, active=None):
+                block_tables=None, active=None, with_logits: bool = False):
     """One greedy decode step.  tokens: [B_loc, 1] int32; pos: [B_loc] int32
     per-slot write positions (a scalar broadcasts to all rows).  With
     ``block_tables`` ([B_loc, pages] int32) the attention caches are paged
@@ -241,7 +241,8 @@ def decode_step(params: Dict, caches: Dict, tokens: Array, pos,
     recurrent state with garbage pad-token input.  Attention pool leaves
     need no masking (null-block redirect); omitting ``active`` keeps the
     legacy all-rows-advance behavior.  Returns (next_token [B_loc,1], new
-    caches)."""
+    caches), and with ``with_logits`` also the fp32 next-token logits of
+    this rank's vocab shard ([B_loc, V_pad/TP])."""
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1),
                            (tokens.shape[0],))
     if active is not None:
@@ -283,7 +284,12 @@ def decode_step(params: Dict, caches: Dict, tokens: Array, pos,
     h = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum("bsd,vd->bsv", h, params["embed"])  # [B,1,V/TP] local
     nxt = vocab_parallel_argmax(logits[:, -1], ctx, v_pad, cfg.vocab_size)
-    return nxt[:, None], new_caches
+    return _step_outputs(nxt, new_caches, logits, with_logits)
+
+
+def _step_outputs(nxt, caches, logits, with_logits: bool):
+    out = (nxt[:, None], caches)
+    return out + (logits[:, -1].astype(jnp.float32),) if with_logits else out
 
 
 def vocab_parallel_argmax(logits_loc: Array, ctx: TPContext,
@@ -475,7 +481,8 @@ def _block_chunk(kind_pair, lp: Dict, lc: Dict, x: Array, bt, slot, off,
 
 def prefill_chunk_step(params: Dict, caches: Dict, tokens: Array,
                        block_tables: Array, slot, off, chunk_len,
-                       ctx: TPContext, cfg: ModelConfig, par: ParallelConfig):
+                       ctx: TPContext, cfg: ModelConfig, par: ParallelConfig,
+                       with_logits: bool = False):
     """One fixed-shape chunk of an incremental paged prefill.
 
     ONE jit program serves every prompt length: tokens is always ``[1, C]``
@@ -492,7 +499,8 @@ def prefill_chunk_step(params: Dict, caches: Dict, tokens: Array,
     view — results are bit-identical regardless of chunk grouping or reuse.
 
     Returns (next_token [1,1] — meaningful only on the FINAL chunk, where
-    row ``chunk_len-1`` is the prompt's last token — and the new caches)."""
+    row ``chunk_len-1`` is the prompt's last token — and the new caches),
+    and with ``with_logits`` also that row's logits as in ``decode_step``."""
     slot = jnp.asarray(slot, jnp.int32)
     off = jnp.asarray(off, jnp.int32)
     chunk_len = jnp.asarray(chunk_len, jnp.int32)
@@ -534,4 +542,4 @@ def prefill_chunk_step(params: Dict, caches: Dict, tokens: Array,
         h, jnp.broadcast_to(chunk_len - 1, (h.shape[0],)))[:, None]
     logits = jnp.einsum("bsd,vd->bsv", h_last, params["embed"])
     nxt = vocab_parallel_argmax(logits[:, -1], ctx, v_pad, cfg.vocab_size)
-    return nxt[:, None], new_caches
+    return _step_outputs(nxt, new_caches, logits, with_logits)

@@ -30,12 +30,12 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import compat
 from repro.configs.base import ATTN, MLA, RWKV, ModelConfig, ParallelConfig
@@ -57,6 +57,9 @@ class ServeConfig:
     #                                    max_batch full-length sequences
     prefill_chunk: int = 32       # chunked-prefill rows per dispatch
     prefix_reuse: bool = True     # hash-chain prefix cache (attention archs)
+    # both programs also return the next-token logits; each request keeps
+    # one fp32 [vocab] row per emitted token in ``Request.logits``
+    keep_logits: bool = False
 
 
 @dataclasses.dataclass
@@ -73,6 +76,11 @@ class Request:
     t_arrival: Optional[float] = None
     t_first_token: Optional[float] = None
     t_finish: Optional[float] = None
+    # teacher forcing: decode step t consumes forced[t] in place of the
+    # model's own token t, so ``output`` holds the model's predictions
+    # along a given continuation (needs max_new_tokens - 1 entries)
+    forced: Optional[Sequence[int]] = None
+    logits: List[np.ndarray] = dataclasses.field(default_factory=list)
 
     def ttft_s(self) -> Optional[float]:
         if self.t_arrival is None or self.t_first_token is None:
@@ -114,7 +122,6 @@ class Server:
         self.par = par
         self.mesh = mesh
         self.sc = sc
-        self.params = params
         from repro.tuning import plan_set_from_parallel
         # paged serving is PER-REPLICA (slots fill from a local queue), so
         # the context carries no dp axes and every program spec is
@@ -127,6 +134,9 @@ class Server:
         params_eval = jax.eval_shape(
             lambda: M.init_model(jax.random.PRNGKey(0), cfg, par))
         self.pspecs = M.param_specs(cfg, par, params_eval)
+        # placed on the mesh ONCE: an unplaced tree would be re-sharded
+        # from its home device on every dispatch
+        self.params = jax.device_put(params, self._shardings(self.pspecs))
 
         self.pages = -(-sc.max_seq // sc.block_size)   # table width
         nb = sc.num_blocks or (sc.max_batch * self.pages + 1)
@@ -136,8 +146,10 @@ class Server:
         self.dense_equiv_blocks = sc.max_batch * self.pages
         cache_sds, self.cache_specs = S.paged_cache_specs(
             cfg, par, nb, sc.block_size, sc.max_batch)
-        self.caches = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                                   cache_sds)
+        self.caches = jax.jit(
+            lambda: jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                                 cache_sds),
+            out_shardings=self._shardings(self.cache_specs))()
         self.positions = np.zeros((sc.max_batch,), np.int32)
         self.slots: List[Optional[Request]] = [None] * sc.max_batch
         self.ready: List[bool] = [False] * sc.max_batch  # prefill complete
@@ -148,19 +160,49 @@ class Server:
         self.prefill_dispatches = 0                 # observability/tests
         self.decode_dispatches = 0
 
+    def _shardings(self, specs):
+        return jax.tree.map(lambda sp: NamedSharding(self.mesh, sp), specs,
+                            is_leaf=lambda x: isinstance(x, P))
+
+    def compile(self) -> Dict[str, float]:
+        """Compile both programs ahead of the first request (later
+        dispatches reuse them); returns each one's compile seconds."""
+        b, pages, c = self.sc.max_batch, self.pages, self.sc.prefill_chunk
+        i32 = jnp.int32
+        scalar = jnp.zeros((), i32)
+        args = {
+            "decode": (self._decode, jnp.zeros((b, 1), i32),
+                       jnp.zeros((b,), i32), jnp.zeros((b, pages), i32),
+                       jnp.zeros((b,), bool)),
+            "chunk": (self._chunk, jnp.zeros((1, c), i32),
+                      jnp.zeros((1, pages), i32), scalar, scalar, scalar),
+        }
+        secs = {}
+        for name, (fn, *rest) in args.items():
+            t0 = time.perf_counter()
+            fn.lower(self.params, self.caches, *rest).compile()
+            secs[name] = time.perf_counter() - t0
+        return secs
+
+    def _out_specs(self):
+        """Programs' outputs: next tokens, caches (+ vocab-sharded logits)."""
+        logits = (P(None, "model"),) if self.sc.keep_logits else ()
+        return (P(None, None), self.cache_specs) + logits
+
     def _make_decode(self):
         ctx, cfg, par = self.ctx, self.cfg, self.par
+        keep = self.sc.keep_logits
 
         def fn(params, caches, tokens, pos, bt, active):
             return S.decode_step(params, caches, tokens, pos, ctx, cfg, par,
-                                 block_tables=bt, active=active)
+                                 block_tables=bt, active=active,
+                                 with_logits=keep)
 
         sm = compat.shard_map(
             fn, mesh=self.mesh,
             in_specs=(self.pspecs, self.cache_specs, P(None, None), P(None),
                       P(None, None), P(None)),
-            out_specs=(P(None, None), self.cache_specs),
-            check_vma=False)
+            out_specs=self._out_specs(), check_vma=False)
         return jax.jit(sm, donate_argnums=(1,))
 
     def _make_chunk(self):
@@ -168,17 +210,18 @@ class Server:
         traced int32 slot/off/chunk_len scalars — every prompt length and
         every slot runs the same compiled signature."""
         ctx, cfg, par = self.ctx, self.cfg, self.par
+        keep = self.sc.keep_logits
 
         def fn(params, caches, tokens, bt, slot, off, chunk_len):
             return S.prefill_chunk_step(params, caches, tokens, bt, slot,
-                                        off, chunk_len, ctx, cfg, par)
+                                        off, chunk_len, ctx, cfg, par,
+                                        with_logits=keep)
 
         sm = compat.shard_map(
             fn, mesh=self.mesh,
             in_specs=(self.pspecs, self.cache_specs, P(None, None),
                       P(None, None), P(), P(), P()),
-            out_specs=(P(None, None), self.cache_specs),
-            check_vma=False)
+            out_specs=self._out_specs(), check_vma=False)
         return jax.jit(sm, donate_argnums=(1,))
 
     # ------------------------------------------------------------ admission
@@ -231,7 +274,7 @@ class Server:
         toks = np.zeros((1, c), np.int32)
         toks[0, :clen] = req.prompt[job.off:job.off + clen]
         bt = job.table.as_array(self.pages)[None]
-        nxt, self.caches = self._chunk(
+        nxt, self.caches, *logits = self._chunk(
             self.params, self.caches, jnp.asarray(toks), jnp.asarray(bt),
             jnp.asarray(slot, jnp.int32), jnp.asarray(job.off, jnp.int32),
             jnp.asarray(clen, jnp.int32))
@@ -243,6 +286,8 @@ class Server:
         self.positions[slot] = n
         self.ready[slot] = True
         req.output.append(int(np.asarray(nxt)[0, 0]))
+        if logits:
+            req.logits.append(np.asarray(logits[0])[0, :self.cfg.vocab_size])
         req.t_first_token = time.perf_counter()
         if self._reuse_ok:
             # now-immutable FULL prompt blocks become reusable by later
@@ -299,20 +344,24 @@ class Server:
         for i, req in enumerate(self.slots):
             if req is not None and self.ready[i]:
                 active[i] = True
-                toks[i, 0] = req.output[-1]
+                toks[i, 0] = (req.output[-1] if req.forced is None
+                              else req.forced[len(req.output) - 1])
                 bts[i] = self.tables[i].as_array(self.pages)
-        nxt, self.caches = self._decode(self.params, self.caches,
-                                        jnp.asarray(toks),
-                                        jnp.asarray(self.positions),
-                                        jnp.asarray(bts),
-                                        jnp.asarray(active))
+        nxt, self.caches, *logits = self._decode(
+            self.params, self.caches, jnp.asarray(toks),
+            jnp.asarray(self.positions), jnp.asarray(bts),
+            jnp.asarray(active))
         self.decode_dispatches += 1
         nxt = np.asarray(nxt)
+        logits = np.asarray(logits[0])[:, :self.cfg.vocab_size] if logits \
+            else None
         finished: List[Request] = []
         for i, req in enumerate(self.slots):
             if req is None or not self.ready[i]:
                 continue
             req.output.append(int(nxt[i, 0]))
+            if logits is not None:
+                req.logits.append(logits[i])
             self.positions[i] += 1
             fin = self._finish_if_done(i)
             if fin is not None:

@@ -167,11 +167,11 @@ class Trainer:
             shardings = jax.tree.map(
                 lambda s: NamedSharding(self.mesh, s), self.pspecs,
                 is_leaf=lambda x: isinstance(x, P))
-            # sharded_init (not jit+out_shardings): init values must not
-            # depend on the mesh layout — see compat.sharded_init.
-            params = compat.sharded_init(
+            # partitionable threefry keeps the values layout-invariant,
+            # and no device ever holds the whole tree
+            params = jax.jit(
                 functools.partial(M.init_model, cfg=self.cfg, par=self.par),
-                shardings)(jax.random.PRNGKey(self.tc.seed))
+                out_shardings=shardings)(jax.random.PRNGKey(self.tc.seed))
             params_eval = jax.eval_shape(
                 lambda: M.init_model(jax.random.PRNGKey(0), self.cfg, self.par))
             opt_specs = adamw.opt_state_specs(self.pspecs, params_eval,

@@ -24,9 +24,10 @@ def test_all_public_symbols_resolve():
 
 
 def test_version_detection():
-    assert compat.JAX_VERSION >= compat.MIN_JAX, (
-        f"installed {compat.JAX_VERSION} predates supported {compat.MIN_JAX}")
-    assert "jax" in compat.version_summary()
+    assert compat.JAX_VERSION == tuple(
+        int(p) for p in jax.__version__.split(".")[:3])
+    assert compat.JAX_VERSION >= (0, 9, 0)
+    assert jax.__version__ in compat.version_summary()
 
 
 def test_shard_map_runs_and_translates_check_kwarg():
@@ -39,7 +40,7 @@ def test_shard_map_runs_and_translates_check_kwarg():
                                   np.arange(4.) * 2)
     with pytest.raises(TypeError):
         compat.shard_map(lambda a: a, mesh=mesh, in_specs=P("x"),
-                         out_specs=P("x"), check_vma=False, check_rep=False)
+                         out_specs=P("x"), check_rep=False)
 
 
 def test_axis_size_outside_mapping():
@@ -67,8 +68,6 @@ def test_interpret_default_env_override(monkeypatch):
 def test_memory_space_helpers():
     ref = compat.VMEM((8, 128), jnp.float32)
     assert ref is not None
-    hbm = compat.hbm_scratch((2, 8, 128), jnp.float32)
-    assert hbm is not None
     assert compat.DMA_SEM is not None
 
 

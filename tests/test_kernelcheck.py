@@ -105,7 +105,6 @@ def _mini_ring(bug=None, acc_shape=None):
     kernel = functools.partial(_mini_kernel, axis_name=AXIS, n_dev=N,
                                bug=bug)
     scratch = [
-        compat.hbm_scratch((N, M_SH, K), a.dtype),      # a_agg
         compat.VMEM(acc_shape or (M_SH, NN), jnp.float32),
         compat.VMEM((M_SH, K), a.dtype),
         compat.VMEM((K, NN), b.dtype),
@@ -114,9 +113,10 @@ def _mini_ring(bug=None, acc_shape=None):
     return compat.pallas_call(
         kernel, grid=(N,),
         in_specs=[pl.BlockSpec(memory_space=compat.ANY)] * 2,
-        out_specs=pl.BlockSpec(memory_space=compat.ANY),
-        out_shape=jax.ShapeDtypeStruct((N * M_SH, NN), a.dtype),
-        scratch_shapes=scratch)(a, b)
+        out_specs=(pl.BlockSpec(memory_space=compat.ANY),) * 2,
+        out_shape=(jax.ShapeDtypeStruct((N * M_SH, NN), a.dtype),
+                   jax.ShapeDtypeStruct((N, M_SH, K), a.dtype)),  # a_agg
+        scratch_shapes=scratch)(a, b)[0]
 
 
 def _case(bug=None, acc_shape=None):
@@ -147,8 +147,8 @@ def test_detects_double_written_slot():
     errs = check_case(_case("double-write"))
     hits = [e for e in errs if "two unordered DMAs" in e]
     assert hits, errs
-    # provenance: the duplicated writer fires at step 1, into scratch0
-    assert any("step=1" in e and "scratch0" in e for e in hits), hits
+    # provenance: the duplicated writer fires at step 1, into work0 (a_agg)
+    assert any("step=1" in e and "work0" in e for e in hits), hits
 
 
 def test_detects_wrong_ring_neighbor():

@@ -12,7 +12,8 @@ from repro.kernels.flash_attention import flash_attention
 
 
 @pytest.mark.parametrize("m,k,n", [(256, 256, 256), (384, 640, 256),
-                                   (128, 1024, 512), (512, 384, 128)])
+                                   (128, 1024, 512), (512, 384, 128),
+                                   (40, 100, 200)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_matmul_kernel(m, k, n, dtype):
     key = jax.random.PRNGKey(m + k + n)
@@ -24,6 +25,21 @@ def test_matmul_kernel(m, k, n, dtype):
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32),
                                atol=tol * np.sqrt(k), rtol=tol)
+
+
+# per-shard GEMMs of minicpm_2b at tp=4 (QKV, o-proj, FFN up, FFN down)
+# and an odd shape
+@pytest.mark.parametrize("m,k,n", [(512, 2304, 1728), (512, 576, 2304),
+                                   (512, 2304, 1440), (512, 1440, 2304),
+                                   (40, 100, 200)])
+def test_plan_blocks_aligned(m, k, n):
+    """The planned blocks are the blocks the wrappers run: each divides its
+    padded dim, bm is a multiple of 16 and bk, bn of 128."""
+    pm, pk, pn = kops.padded_dims(m, k, n)
+    bm, bk, bn = kops.plan_blocks(m, k, n)
+    assert (pm % bm, pk % bk, pn % bn) == (0, 0, 0)
+    assert (bm % 16, bk % 128, bn % 128) == (0, 0, 0)
+    assert kops.plan_blocks(512, 2048, 1024) == (256, 512, 256)
 
 
 @pytest.mark.parametrize("b,hq,hkv,s,d", [

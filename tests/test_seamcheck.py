@@ -224,11 +224,14 @@ def _lint(src, path="src/repro/models/fixture.py"):
 
 
 def test_lint_compat_import_rule():
-    vs = _lint("from jax.experimental.shard_map import shard_map\n")
-    assert [v.rule for v in vs] == ["compat-import"]
+    for src in ("from jax.experimental.shard_map import shard_map\n",
+                "from jax.core import Literal\n", "from jax import core\n",
+                "import jax.core\n", "ok = isinstance(v, jax.core.Var)\n"):
+        assert [v.rule for v in _lint(src)] == ["compat-import"], src
     # exempt inside compat/
-    assert _lint("from jax.experimental.shard_map import shard_map\n",
-                 "src/repro/compat/shims.py") == []
+    for src in ("from jax.experimental.shard_map import shard_map\n",
+                "from jax.core import Literal\n"):
+        assert _lint(src, "src/repro/compat/shims.py") == [], src
 
 
 def test_lint_bare_shard_map_rule():
