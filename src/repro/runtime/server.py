@@ -25,6 +25,13 @@ allocator actually needs the space.
 
 ``serve`` runs the queue through the ChunkScheduler; the server OWNS
 request timing (t_arrival / t_first_token / t_finish — see ``Request``).
+
+Host spans (``jax.profiler.TraceAnnotation``; about a microsecond each when
+no profiler runs) put each dispatch's phases on the profiler's clock, beside
+the device's: ``serve.decode.launch`` / ``.readback`` / ``.emit`` in
+``step``, ``serve.chunk.launch`` in ``prefill_chunk`` and, on a final chunk,
+``serve.chunk.readback`` / ``.emit``.  The two programs trace as
+``jit_serve_decode`` and ``jit_serve_chunk``.
 """
 from __future__ import annotations
 
@@ -193,13 +200,13 @@ class Server:
         ctx, cfg, par = self.ctx, self.cfg, self.par
         keep = self.sc.keep_logits
 
-        def fn(params, caches, tokens, pos, bt, active):
+        def serve_decode(params, caches, tokens, pos, bt, active):
             return S.decode_step(params, caches, tokens, pos, ctx, cfg, par,
                                  block_tables=bt, active=active,
                                  with_logits=keep)
 
         sm = compat.shard_map(
-            fn, mesh=self.mesh,
+            serve_decode, mesh=self.mesh,
             in_specs=(self.pspecs, self.cache_specs, P(None, None), P(None),
                       P(None, None), P(None)),
             out_specs=self._out_specs(), check_vma=False)
@@ -212,13 +219,13 @@ class Server:
         ctx, cfg, par = self.ctx, self.cfg, self.par
         keep = self.sc.keep_logits
 
-        def fn(params, caches, tokens, bt, slot, off, chunk_len):
+        def serve_chunk(params, caches, tokens, bt, slot, off, chunk_len):
             return S.prefill_chunk_step(params, caches, tokens, bt, slot,
                                         off, chunk_len, ctx, cfg, par,
                                         with_logits=keep)
 
         sm = compat.shard_map(
-            fn, mesh=self.mesh,
+            serve_chunk, mesh=self.mesh,
             in_specs=(self.pspecs, self.cache_specs, P(None, None),
                       P(None, None), P(), P(), P()),
             out_specs=self._out_specs(), check_vma=False)
@@ -271,31 +278,37 @@ class Server:
         n = len(req.prompt)
         c = self.sc.prefill_chunk
         clen = min(c, n - job.off)
-        toks = np.zeros((1, c), np.int32)
-        toks[0, :clen] = req.prompt[job.off:job.off + clen]
-        bt = job.table.as_array(self.pages)[None]
-        nxt, self.caches, *logits = self._chunk(
-            self.params, self.caches, jnp.asarray(toks), jnp.asarray(bt),
-            jnp.asarray(slot, jnp.int32), jnp.asarray(job.off, jnp.int32),
-            jnp.asarray(clen, jnp.int32))
+        with jax.profiler.TraceAnnotation("serve.chunk.launch"):
+            toks = np.zeros((1, c), np.int32)
+            toks[0, :clen] = req.prompt[job.off:job.off + clen]
+            bt = job.table.as_array(self.pages)[None]
+            nxt, self.caches, *logits = self._chunk(
+                self.params, self.caches, jnp.asarray(toks), jnp.asarray(bt),
+                jnp.asarray(slot, jnp.int32), jnp.asarray(job.off, jnp.int32),
+                jnp.asarray(clen, jnp.int32))
         self.prefill_dispatches += 1
         job.off += clen
         if job.off < n:
             return False
         # final chunk: its row clen-1 is the prompt's last position
-        self.positions[slot] = n
-        self.ready[slot] = True
-        req.output.append(int(np.asarray(nxt)[0, 0]))
-        if logits:
-            req.logits.append(np.asarray(logits[0])[0, :self.cfg.vocab_size])
-        req.t_first_token = time.perf_counter()
-        if self._reuse_ok:
-            # now-immutable FULL prompt blocks become reusable by later
-            # admissions (the trailing partial block keeps growing under
-            # decode — never shared)
-            self.pool.register(
-                job.table.blocks[:n // self.sc.block_size], req.prompt)
-        self._finish_if_done(slot)
+        with jax.profiler.TraceAnnotation("serve.chunk.readback"):
+            tok = int(np.asarray(nxt)[0, 0])
+            row = (np.asarray(logits[0])[0, :self.cfg.vocab_size] if logits
+                   else None)
+        with jax.profiler.TraceAnnotation("serve.chunk.emit"):
+            self.positions[slot] = n
+            self.ready[slot] = True
+            req.output.append(tok)
+            if row is not None:
+                req.logits.append(row)
+            req.t_first_token = time.perf_counter()
+            if self._reuse_ok:
+                # now-immutable FULL prompt blocks become reusable by later
+                # admissions (the trailing partial block keeps growing
+                # under decode — never shared)
+                self.pool.register(
+                    job.table.blocks[:n // self.sc.block_size], req.prompt)
+            self._finish_if_done(slot)
         return True
 
     def admit(self, req: Request) -> bool:
@@ -337,35 +350,38 @@ class Server:
         readback."""
         if not any(self.ready):
             return []
-        b = self.sc.max_batch
-        toks = np.zeros((b, 1), np.int32)
-        bts = np.zeros((b, self.pages), np.int32)
-        active = np.zeros((b,), bool)
-        for i, req in enumerate(self.slots):
-            if req is not None and self.ready[i]:
-                active[i] = True
-                toks[i, 0] = (req.output[-1] if req.forced is None
-                              else req.forced[len(req.output) - 1])
-                bts[i] = self.tables[i].as_array(self.pages)
-        nxt, self.caches, *logits = self._decode(
-            self.params, self.caches, jnp.asarray(toks),
-            jnp.asarray(self.positions), jnp.asarray(bts),
-            jnp.asarray(active))
+        with jax.profiler.TraceAnnotation("serve.decode.launch"):
+            b = self.sc.max_batch
+            toks = np.zeros((b, 1), np.int32)
+            bts = np.zeros((b, self.pages), np.int32)
+            active = np.zeros((b,), bool)
+            for i, req in enumerate(self.slots):
+                if req is not None and self.ready[i]:
+                    active[i] = True
+                    toks[i, 0] = (req.output[-1] if req.forced is None
+                                  else req.forced[len(req.output) - 1])
+                    bts[i] = self.tables[i].as_array(self.pages)
+            nxt, self.caches, *logits = self._decode(
+                self.params, self.caches, jnp.asarray(toks),
+                jnp.asarray(self.positions), jnp.asarray(bts),
+                jnp.asarray(active))
         self.decode_dispatches += 1
-        nxt = np.asarray(nxt)
-        logits = np.asarray(logits[0])[:, :self.cfg.vocab_size] if logits \
-            else None
+        with jax.profiler.TraceAnnotation("serve.decode.readback"):
+            nxt = np.asarray(nxt)
+            logits = (np.asarray(logits[0])[:, :self.cfg.vocab_size]
+                      if logits else None)
         finished: List[Request] = []
-        for i, req in enumerate(self.slots):
-            if req is None or not self.ready[i]:
-                continue
-            req.output.append(int(nxt[i, 0]))
-            if logits is not None:
-                req.logits.append(logits[i])
-            self.positions[i] += 1
-            fin = self._finish_if_done(i)
-            if fin is not None:
-                finished.append(fin)
+        with jax.profiler.TraceAnnotation("serve.decode.emit"):
+            for i, req in enumerate(self.slots):
+                if req is None or not self.ready[i]:
+                    continue
+                req.output.append(int(nxt[i, 0]))
+                if logits is not None:
+                    req.logits.append(logits[i])
+                self.positions[i] += 1
+                fin = self._finish_if_done(i)
+                if fin is not None:
+                    finished.append(fin)
         return finished
 
     def serve(self, requests: List[Request]) -> List[Request]:

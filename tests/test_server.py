@@ -1,5 +1,6 @@
 """Continuous-batching server: admission, slot recycling, determinism."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh
@@ -61,6 +62,25 @@ def test_keep_logits_and_teacher_forcing():
     assert forced.output == free.output
     np.testing.assert_array_equal(np.stack(forced.logits),
                                   np.stack(free.logits))
+
+
+@pytest.mark.parametrize("program,module", [("decode", "jit_serve_decode"),
+                                            ("chunk", "jit_serve_chunk")])
+def test_programs_lower_under_their_own_names(server, program, module):
+    """The decode and chunk programs lower to modules named for what they
+    do, so a device trace tells their executions apart."""
+    srv, _ = server
+    b, pages, c = srv.sc.max_batch, srv.pages, srv.sc.prefill_chunk
+    i32 = jnp.int32
+    scalar = jnp.zeros((), i32)
+    fn, *args = {
+        "decode": (srv._decode, jnp.zeros((b, 1), i32), jnp.zeros((b,), i32),
+                   jnp.zeros((b, pages), i32), jnp.zeros((b,), bool)),
+        "chunk": (srv._chunk, jnp.zeros((1, c), i32),
+                  jnp.zeros((1, pages), i32), scalar, scalar, scalar),
+    }[program]
+    text = fn.lower(srv.params, srv.caches, *args).as_text()
+    assert text.startswith(f"module @{module} ")
 
 
 def test_compile_is_reused_by_dispatch():
