@@ -228,12 +228,15 @@ def gqa_decode(p: Dict, x: Array, cache: Dict, pos: Array, ctx: TPContext,
 
 
 def gqa_decode_paged(p: Dict, x: Array, cache: Dict, bt: Array, pos: Array,
-                     ctx: TPContext, cfg: ModelConfig) -> Tuple[Array, Dict]:
+                     ctx: TPContext, cfg: ModelConfig, layer=None
+                     ) -> Tuple[Array, Dict]:
     """``gqa_decode`` through the paged KV pool: identical math, but K/V
     rows live in shared physical blocks addressed through each slot's
     block table.  cache: {k,v: [N_blocks, bs, Hkv_l, Dh]}; bt: [B, P]
     int32 (inactive slots pass all-zero rows — their writes land in the
-    null block and their outputs are discarded by the server)."""
+    null block and their outputs are discarded by the server).  With
+    ``layer`` the leaves are layer-stacked and read/written in place at
+    that layer (``layers.pool_update_rows``)."""
     tp = ctx.tp
     d = AttnDims.of(cfg, tp)
     hl, hkvl = d.h_pad // tp, d.hkv_pad // tp
@@ -254,10 +257,10 @@ def gqa_decode_paged(p: Dict, x: Array, cache: Dict, bt: Array, pos: Array,
         q = layers.apply_rope(q, pb, cfg.rope_theta)
         k = layers.apply_rope(k, pb, cfg.rope_theta)
 
-    ck = layers.pool_update_rows(cache["k"], k, bt, pos)
-    cv = layers.pool_update_rows(cache["v"], v, bt, pos)
-    kview = layers.pool_view(ck, bt)               # [B, P*bs, Hkv_l, Dh]
-    vview = layers.pool_view(cv, bt)
+    ck = layers.pool_update_rows(cache["k"], k, bt, pos, layer=layer)
+    cv = layers.pool_update_rows(cache["v"], v, bt, pos, layer=layer)
+    kview = layers.pool_view(ck, bt, layer)        # [B, P*bs, Hkv_l, Dh]
+    vview = layers.pool_view(cv, bt, layer)
 
     s_tot = kview.shape[1]
     group = hl // hkvl
@@ -275,8 +278,8 @@ def gqa_decode_paged(p: Dict, x: Array, cache: Dict, bt: Array, pos: Array,
 
 
 def gqa_prefill_chunk(p: Dict, x: Array, cache: Dict, bt: Array, off,
-                      chunk_len, ctx: TPContext, cfg: ModelConfig
-                      ) -> Tuple[Array, Dict]:
+                      chunk_len, ctx: TPContext, cfg: ModelConfig,
+                      layer=None) -> Tuple[Array, Dict]:
     """One fixed-size chunk of an incremental paged prefill.
 
     x: [B, C, D] REPLICATED (chunked prefill always runs the replicated
@@ -287,7 +290,8 @@ def gqa_prefill_chunk(p: Dict, x: Array, cache: Dict, bt: Array, off,
     to the null block), then scores mask ``kpos <= off + i`` per chunk row
     over the whole gathered view — earlier chunks' and reused prefix
     blocks' K/V participate exactly as in a full prefill, so chunked
-    results are independent of the chunk grouping."""
+    results are independent of the chunk grouping.  ``layer``: as in
+    ``gqa_decode_paged``."""
     tp = ctx.tp
     d = AttnDims.of(cfg, tp)
     hl, hkvl = d.h_pad // tp, d.hkv_pad // tp
@@ -310,10 +314,12 @@ def gqa_prefill_chunk(p: Dict, x: Array, cache: Dict, bt: Array, off,
 
     offv = jnp.broadcast_to(off, (b,))
     lenv = jnp.broadcast_to(jnp.asarray(chunk_len, jnp.int32), (b,))
-    ck = layers.pool_update_rows(cache["k"], k, bt, offv, valid=lenv)
-    cv = layers.pool_update_rows(cache["v"], v, bt, offv, valid=lenv)
-    kview = layers.pool_view(ck, bt)
-    vview = layers.pool_view(cv, bt)
+    ck = layers.pool_update_rows(cache["k"], k, bt, offv, valid=lenv,
+                                 layer=layer)
+    cv = layers.pool_update_rows(cache["v"], v, bt, offv, valid=lenv,
+                                 layer=layer)
+    kview = layers.pool_view(ck, bt, layer)
+    vview = layers.pool_view(cv, bt, layer)
 
     s_tot = kview.shape[1]
     group = hl // hkvl
@@ -488,11 +494,13 @@ def mla_decode(p: Dict, x: Array, cache: Dict, pos: Array, ctx: TPContext,
 
 
 def mla_decode_paged(p: Dict, x: Array, cache: Dict, bt: Array, pos: Array,
-                     ctx: TPContext, cfg: ModelConfig) -> Tuple[Array, Dict]:
+                     ctx: TPContext, cfg: ModelConfig, layer=None
+                     ) -> Tuple[Array, Dict]:
     """Absorbed-form MLA decode over the paged latent pool.  cache:
     {c: [N_blocks, bs, rank], kr: [N_blocks, bs, rope_dim]}; bt: [B, P].
     The gathered per-row views are shaped like the dense caches, so the
-    fused decode kernel path applies unchanged."""
+    fused decode kernel path applies unchanged.  ``layer``: as in
+    ``gqa_decode_paged``."""
     m = cfg.mla
     tp = ctx.tp
     h_pad = pad_heads(cfg.num_heads, tp)
@@ -524,10 +532,10 @@ def mla_decode_paged(p: Dict, x: Array, cache: Dict, bt: Array, pos: Array,
     q_eff = jnp.einsum("bohd,rhd->bohr", q_nope.astype(jnp.float32),
                        w_uk.astype(jnp.float32))
 
-    cc = layers.pool_update_rows(cache["c"], kv_lat, bt, pos)
-    cr = layers.pool_update_rows(cache["kr"], k_rope, bt, pos)
-    cview = layers.pool_view(cc, bt)               # [B, P*bs, rank]
-    rview = layers.pool_view(cr, bt)
+    cc = layers.pool_update_rows(cache["c"], kv_lat, bt, pos, layer=layer)
+    cr = layers.pool_update_rows(cache["kr"], k_rope, bt, pos, layer=layer)
+    cview = layers.pool_view(cc, bt, layer)        # [B, P*bs, rank]
+    rview = layers.pool_view(cr, bt, layer)
 
     if ctx.use_kernels:
         from repro.kernels.mla_decode import mla_decode_attention
@@ -551,12 +559,13 @@ def mla_decode_paged(p: Dict, x: Array, cache: Dict, bt: Array, pos: Array,
 
 
 def mla_prefill_chunk(p: Dict, x: Array, cache: Dict, bt: Array, off,
-                      chunk_len, ctx: TPContext, cfg: ModelConfig
-                      ) -> Tuple[Array, Dict]:
+                      chunk_len, ctx: TPContext, cfg: ModelConfig,
+                      layer=None) -> Tuple[Array, Dict]:
     """Absorbed-form chunked prefill over the paged latent pool: the same
     math as ``mla_decode_paged`` with C query rows at a time (scores are
     identical to the non-absorbed prefill by associativity — q_nope·(W_uk c)
-    = (q_nope W_uk)·c, both in fp32).  x: [B, C, D] replicated."""
+    = (q_nope W_uk)·c, both in fp32).  x: [B, C, D] replicated.
+    ``layer``: as in ``gqa_decode_paged``."""
     m = cfg.mla
     tp = ctx.tp
     h_pad = pad_heads(cfg.num_heads, tp)
@@ -591,10 +600,12 @@ def mla_prefill_chunk(p: Dict, x: Array, cache: Dict, bt: Array, off,
 
     offv = jnp.broadcast_to(off, (b,))
     lenv = jnp.broadcast_to(jnp.asarray(chunk_len, jnp.int32), (b,))
-    cc = layers.pool_update_rows(cache["c"], kv_lat, bt, offv, valid=lenv)
-    cr = layers.pool_update_rows(cache["kr"], k_rope, bt, offv, valid=lenv)
-    cview = layers.pool_view(cc, bt)
-    rview = layers.pool_view(cr, bt)
+    cc = layers.pool_update_rows(cache["c"], kv_lat, bt, offv, valid=lenv,
+                                 layer=layer)
+    cr = layers.pool_update_rows(cache["kr"], k_rope, bt, offv, valid=lenv,
+                                 layer=layer)
+    cview = layers.pool_view(cc, bt, layer)
+    rview = layers.pool_view(cr, bt, layer)
 
     s_tot = cview.shape[1]
     scores = (jnp.einsum("bqhr,bsr->bhqs", q_eff, cview.astype(jnp.float32))

@@ -205,7 +205,8 @@ def cache_update_rows(cache: Array, new: Array, pos: Array) -> Array:
 
 
 def pool_update_rows(pool: Array, new: Array, bt: Array, start: Array,
-                     valid: Optional[Array] = None) -> Array:
+                     valid: Optional[Array] = None,
+                     layer: Optional[Array] = None) -> Array:
     """Paged KV write THROUGH a block table.
 
     pool: [N_blocks, bs, ...] physical blocks; new: [B, L, ...]; bt: [B, P]
@@ -216,32 +217,41 @@ def pool_update_rows(pool: Array, new: Array, bt: Array, start: Array,
     they redirect into physical block 0, the pool's reserved NULL block
     (never allocated, never read unmasked).  Inactive decode slots get the
     same treatment for free: their bt rows are all zeros.  Real rows never
-    collide (tables are disjoint and block 0 is never in a table)."""
-    n_blocks, bs = pool.shape[0], pool.shape[1]
+    collide (tables are disjoint and block 0 is never in a table).
+
+    ``layer`` (int32 scalar, optional): ``pool`` is then a layer-STACKED
+    leaf ``[layers, N_blocks, bs, ...]`` and the B*L rows scatter straight
+    into ``pool[layer, blk, row]`` — no layer is sliced out or rebuilt, so
+    a pool carried through the serving programs' layer scan is written in
+    place.  Either way the whole (updated) ``pool`` is returned."""
+    bs = pool.shape[1 if layer is None else 2]
     b, l = new.shape[0], new.shape[1]
     logical = start[:, None] + jnp.arange(l, dtype=jnp.int32)       # [B, L]
     blk = jnp.take_along_axis(
         bt, jnp.clip(logical // bs, 0, bt.shape[1] - 1), axis=1)
-    flat = blk * bs + logical % bs                                  # [B, L]
     if valid is not None:
         ok = jnp.arange(l, dtype=jnp.int32)[None, :] < valid[:, None]
-        flat = jnp.where(ok, flat, logical % bs)    # null-block rows
-    pool_flat = pool.reshape(n_blocks * bs, *pool.shape[2:])
-    return pool_flat.at[flat.reshape(-1)].set(
-        new.reshape(b * l, *new.shape[2:]).astype(pool.dtype)
-    ).reshape(pool.shape)
+        blk = jnp.where(ok, blk, 0)                 # null-block rows
+    rows = new.reshape(b * l, *new.shape[2:]).astype(pool.dtype)
+    idx = (blk.reshape(-1), (logical % bs).reshape(-1))
+    if layer is not None:
+        idx = (layer,) + idx
+    return pool.at[idx].set(rows)
 
 
-def pool_view(pool: Array, bt: Array) -> Array:
+def pool_view(pool: Array, bt: Array, layer: Optional[Array] = None
+              ) -> Array:
     """Gather each row's logical K/V timeline through its block table.
 
     pool: [N_blocks, bs, ...]; bt: [B, P] -> [B, P*bs, ...].  Logical
     position s of row b reads ``pool[bt[b, s // bs], s % bs]``; positions
     past the row's true length land in stale or null-block rows and MUST
-    be masked by the caller (attention masks on pos already do)."""
-    g = pool[bt]                                   # [B, P, bs, ...]
-    return g.reshape(bt.shape[0], bt.shape[1] * pool.shape[1],
-                     *pool.shape[2:])
+    be masked by the caller (attention masks on pos already do).  With
+    ``layer`` (int32 scalar) ``pool`` is a layer-stacked leaf
+    ``[layers, N_blocks, bs, ...]`` and the view gathers ``pool[layer, bt]``
+    straight from it; the view's shape and contents are the same."""
+    g = pool[bt] if layer is None else pool[layer, bt]   # [B, P, bs, ...]
+    return g.reshape(bt.shape[0], bt.shape[1] * g.shape[2], *g.shape[3:])
 
 
 def take_rows(x: Array, idx: Array) -> Array:

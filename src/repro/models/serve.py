@@ -25,6 +25,19 @@ Continuous-batching contract (what the runtime Server relies on):
   ``lengths - 1`` per row.  The returned caches are therefore exactly what
   a token-by-token decode of the unpadded prompt would have produced —
   admission scatters them into a slot's rows in one dispatch.
+
+How caches flow through the scanned layer loop (``_scan_periods``): the
+PAGED programs (``block_tables`` given, and every ``prefill_chunk_step``)
+carry the stacked ``caches["periods"]`` leaves in the ``lax.scan`` carry
+beside ``x`` and scan over (period params, layer index).  Each period
+scatters its new K/V rows straight into ``pool[layer, block, row]`` and
+gathers its view from ``pool[layer, bt]`` (``layers.pool_update_rows`` /
+``pool_view`` with ``layer``); the dense per-slot state leaves (Mamba
+conv/SSM, RWKV wkv/shift) are read at, and written back with a
+dynamic-update-slice at, the same index.  No layer is sliced out and no
+stacked output is rebuilt, so the donated pool is updated in place.  The
+DENSE caches (``block_tables=None``) map over the layers as scan xs/ys.
+The unscanned ``lead`` layers hold unstacked leaves and take no index.
 """
 from __future__ import annotations
 
@@ -166,14 +179,16 @@ def paged_cache_specs(cfg: ModelConfig, par: ParallelConfig, num_blocks: int,
 # Decode
 # ---------------------------------------------------------------------------
 def _mixer_decode(kind: str, p: Dict, x: Array, cache: Dict, pos, ctx,
-                  cfg: ModelConfig, bt=None):
+                  cfg: ModelConfig, bt=None, li=None):
     if kind == ATTN:
         if bt is not None:
-            return attention.gqa_decode_paged(p, x, cache, bt, pos, ctx, cfg)
+            return attention.gqa_decode_paged(p, x, cache, bt, pos, ctx, cfg,
+                                              li)
         return attention.gqa_decode(p, x, cache, pos, ctx, cfg)
     if kind == MLA:
         if bt is not None:
-            return attention.mla_decode_paged(p, x, cache, bt, pos, ctx, cfg)
+            return attention.mla_decode_paged(p, x, cache, bt, pos, ctx, cfg,
+                                              li)
         return attention.mla_decode(p, x, cache, pos, ctx, cfg)
     if kind == MAMBA:
         return mamba.mamba_decode(p, x, cache, pos, ctx, cfg)
@@ -193,6 +208,47 @@ def _ffn_decode(kind: str, p: Dict, x: Array, cache: Dict, ctx,
     raise ValueError(kind)
 
 
+def _at_layer(cache: Dict, li) -> Dict:
+    """Layer ``li`` of a carried layer-stacked cache tree; the tree itself
+    when ``li`` is None (an unstacked lead layer, or a dense scan's xs)."""
+    if li is None:
+        return cache
+    return jax.tree.map(
+        lambda v: lax.dynamic_index_in_dim(v, li, keepdims=False), cache)
+
+
+def _put_layer(cache: Dict, new: Dict, li) -> Dict:
+    """Write one layer's tree back into the carried stack at ``li``
+    (``new`` itself when ``li`` is None)."""
+    if li is None:
+        return new
+    return jax.tree.map(
+        lambda v, n: lax.dynamic_update_index_in_dim(v, n.astype(v.dtype),
+                                                     li, 0), cache, new)
+
+
+def _scan_periods(body, x: Array, periods_p, periods_c, n: int,
+                  carried: bool):
+    """Run ``body(x, p, c, li) -> (x, new_c)`` over the ``n`` stacked
+    periods.  ``carried``: the caches ride the scan CARRY whole and each
+    period reads and writes its layer of the stacked leaves at the traced
+    index ``li`` — the compiled loop then updates the paged pools in place
+    instead of slicing each layer out and restacking every leaf.  Otherwise
+    they map over the layers as xs/ys and ``body`` gets ``li=None``."""
+    periods_p, periods_c = tuple(periods_p), tuple(periods_c)
+    if not carried:
+        x, new = lax.scan(lambda x, pc: body(x, *pc, None), x,
+                          (periods_p, periods_c))
+        return x, list(new)
+
+    def step(carry, xs):
+        return body(carry[0], xs[0], carry[1], xs[1]), None
+
+    (x, new), _ = lax.scan(step, (x, periods_c),
+                           (periods_p, jnp.arange(n, dtype=jnp.int32)))
+    return x, list(new)
+
+
 def _freeze_inactive(new: Dict, old: Dict, active) -> Dict:
     """Mask a dense per-slot cache write-back to the ACTIVE rows only.  The
     state families (Mamba conv/SSM, RWKV wkv/token-shift) rewrite every
@@ -208,21 +264,30 @@ def _freeze_inactive(new: Dict, old: Dict, active) -> Dict:
 
 def _block_decode(kind_pair, lp: Dict, lc: Dict, x: Array, pos, ctx, cfg,
                   par: ParallelConfig, z3=None, layer=None, bt=None,
-                  active=None):
+                  active=None, li=None):
+    """``li``: this block's index into the carried layer-stacked caches
+    ``lc`` (paged scan; None for unstacked leaves)."""
     lp = _maybe_gather_zero3(lp, par, z3)
     ctx = ctx.with_layer(layer)
-    dy, mc = _mixer_decode(kind_pair[0], lp["mixer"], x, lc["mixer"], pos,
-                           ctx, cfg, bt=bt)
-    if active is not None and (kind_pair[0] in (MAMBA, RWKV) or bt is None):
-        # paged attention pools ([num_blocks, ...]) are already protected
-        # by the null-block redirect; every dense [B, ...] cache needs the
-        # row mask
-        mc = _freeze_inactive(mc, lc["mixer"], active)
+    if bt is not None and kind_pair[0] in (ATTN, MLA):
+        # paged attention pools ([num_blocks, ...]) are written in place
+        # and already protected by the null-block redirect
+        dy, mc = _mixer_decode(kind_pair[0], lp["mixer"], x, lc["mixer"],
+                               pos, ctx, cfg, bt=bt, li=li)
+    else:
+        old = _at_layer(lc["mixer"], li)
+        dy, mc = _mixer_decode(kind_pair[0], lp["mixer"], x, old, pos, ctx,
+                               cfg, bt=bt)
+        if active is not None:
+            # every dense [B, ...] cache needs the row mask
+            mc = _freeze_inactive(mc, old, active)
+        mc = _put_layer(lc["mixer"], mc, li)
     x = x + dy
-    dy, fc = _ffn_decode(kind_pair[1], lp["ffn"], x, lc["ffn"], ctx, cfg)
+    old = _at_layer(lc["ffn"], li)
+    dy, fc = _ffn_decode(kind_pair[1], lp["ffn"], x, old, ctx, cfg)
     if active is not None and kind_pair[1] == RWKV:
-        fc = _freeze_inactive(fc, lc["ffn"], active)
-    return x + dy, {"mixer": mc, "ffn": fc}
+        fc = _freeze_inactive(fc, old, active)
+    return x + dy, {"mixer": mc, "ffn": _put_layer(lc["ffn"], fc, li)}
 
 
 def decode_step(params: Dict, caches: Dict, tokens: Array, pos,
@@ -265,21 +330,20 @@ def decode_step(params: Dict, caches: Dict, tokens: Array, pos,
                               bt=block_tables, active=active)
         new_caches["lead"].append(nc)
 
-    def period_body(x, xs):
-        stacked_p, stacked_c = xs
+    def period_body(x, stacked_p, stacked_c, li):
         ncs = []
         for p_i, kp in enumerate(cfg.pattern):
             x, nc = _block_decode(kp, stacked_p[p_i], stacked_c[p_i], x, pos,
                                   ctx, cfg, par,
                                   z3["periods"][p_i] if z3["periods"] else None,
                                   layer=lead + p_i, bt=block_tables,
-                                  active=active)
+                                  active=active, li=li)
             ncs.append(nc)
         return x, tuple(ncs)
 
-    x, stacked_new = lax.scan(
-        period_body, x, (tuple(params["periods"]), tuple(caches["periods"])))
-    new_caches["periods"] = list(stacked_new)
+    x, new_caches["periods"] = _scan_periods(
+        period_body, x, params["periods"], caches["periods"], n_periods(cfg),
+        carried=block_tables is not None)
 
     h = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum("bsd,vd->bsv", h, params["embed"])  # [B,1,V/TP] local
@@ -424,19 +488,20 @@ def _store_slot_state(cache: Dict, st: Dict, slot) -> Dict:
 
 
 def _mixer_chunk(kind: str, p: Dict, x: Array, cache: Dict, bt, slot, off,
-                 chunk_len, first, ctx, cfg: ModelConfig):
+                 chunk_len, first, ctx, cfg: ModelConfig, li=None):
     if kind == ATTN:
         return attention.gqa_prefill_chunk(p, x, cache, bt, off, chunk_len,
-                                           ctx, cfg)
+                                           ctx, cfg, li)
     if kind == MLA:
         return attention.mla_prefill_chunk(p, x, cache, bt, off, chunk_len,
-                                           ctx, cfg)
+                                           ctx, cfg, li)
     # state families: thread the slot's recurrent state across chunks.  The
     # first chunk zeroes it (a freed slot's stale state must not leak into
     # the next admission); lengths are chunk-RELATIVE — rows past chunk_len
     # freeze the state exactly like prompt right-padding.
     lenv = jnp.broadcast_to(jnp.asarray(chunk_len, jnp.int32), (x.shape[0],))
-    st = _slot_state(cache, slot)
+    layer_c = _at_layer(cache, li)
+    st = _slot_state(layer_c, slot)
     st = jax.tree.map(lambda v: jnp.where(first, jnp.zeros_like(v), v), st)
     if kind == MAMBA:
         y, ns = mamba.mamba_train(p, x, ctx, cfg, with_cache=True,
@@ -446,11 +511,11 @@ def _mixer_chunk(kind: str, p: Dict, x: Array, cache: Dict, bt, slot, off,
                                      lengths=lenv, cache=st)
     else:
         raise ValueError(kind)
-    return y, _store_slot_state(cache, ns, slot)
+    return y, _put_layer(cache, _store_slot_state(layer_c, ns, slot), li)
 
 
 def _ffn_chunk(kind: str, p: Dict, x: Array, cache: Dict, slot, chunk_len,
-               first, ctx, cfg: ModelConfig):
+               first, ctx, cfg: ModelConfig, li=None):
     if kind == DENSE_FFN:
         return ffn.ffn_train(p, x, ctx, cfg.norm_eps), cache
     lenv = jnp.broadcast_to(jnp.asarray(chunk_len, jnp.int32), (x.shape[0],))
@@ -458,24 +523,26 @@ def _ffn_chunk(kind: str, p: Dict, x: Array, cache: Dict, slot, chunk_len,
         y, _ = ffn.moe_train(p, x, ctx, cfg, lengths=lenv)
         return y, cache
     if kind == RWKV:
-        st = _slot_state(cache, slot)
+        layer_c = _at_layer(cache, li)
+        st = _slot_state(layer_c, slot)
         st = jax.tree.map(lambda v: jnp.where(first, jnp.zeros_like(v), v), st)
         y, ns = rwkv.rwkv_channel_train(p, x, ctx, cfg, with_cache=True,
                                         lengths=lenv, cache=st)
-        return y, _store_slot_state(cache, ns, slot)
+        return y, _put_layer(cache, _store_slot_state(layer_c, ns, slot), li)
     raise ValueError(kind)
 
 
 def _block_chunk(kind_pair, lp: Dict, lc: Dict, x: Array, bt, slot, off,
                  chunk_len, first, ctx, cfg, par: ParallelConfig, z3=None,
-                 layer=None):
+                 layer=None, li=None):
+    """``li``: as in ``_block_decode``."""
     lp = _maybe_gather_zero3(lp, par, z3)
     ctx = ctx.with_layer(layer)
     dy, mc = _mixer_chunk(kind_pair[0], lp["mixer"], x, lc["mixer"], bt, slot,
-                          off, chunk_len, first, ctx, cfg)
+                          off, chunk_len, first, ctx, cfg, li)
     x = x + dy
     dy, fc = _ffn_chunk(kind_pair[1], lp["ffn"], x, lc["ffn"], slot,
-                        chunk_len, first, ctx, cfg)
+                        chunk_len, first, ctx, cfg, li)
     return x + dy, {"mixer": mc, "ffn": fc}
 
 
@@ -521,21 +588,20 @@ def prefill_chunk_step(params: Dict, caches: Dict, tokens: Array,
                              layer=i)
         new_caches["lead"].append(nc)
 
-    def period_body(x, xs):
-        stacked_p, stacked_c = xs
+    def period_body(x, stacked_p, stacked_c, li):
         ncs = []
         for p_i, kp in enumerate(cfg.pattern):
             x, nc = _block_chunk(kp, stacked_p[p_i], stacked_c[p_i], x,
                                  block_tables, slot, off, chunk_len, first,
                                  ctx, cfg, par,
                                  z3["periods"][p_i] if z3["periods"] else None,
-                                 layer=lead + p_i)
+                                 layer=lead + p_i, li=li)
             ncs.append(nc)
         return x, tuple(ncs)
 
-    x, stacked_new = lax.scan(
-        period_body, x, (tuple(params["periods"]), tuple(caches["periods"])))
-    new_caches["periods"] = list(stacked_new)
+    x, new_caches["periods"] = _scan_periods(
+        period_body, x, params["periods"], caches["periods"], n_periods(cfg),
+        carried=True)
 
     h = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     h_last = layers.take_rows(

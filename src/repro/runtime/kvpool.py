@@ -4,7 +4,9 @@ Host-side allocator for the serving runtime (no device arrays move through
 here): physical KV storage lives in ``[num_blocks, block_size, ...]`` pool
 leaves, and each decode slot owns a BLOCK TABLE — logical block ``i`` of
 the slot's sequence maps to physical block ``table.blocks[i]``.  Block ids
-are layer-agnostic: one allocation addresses every layer's pool leaf.
+are layer-agnostic: one allocation addresses every layer's pool leaf.  The
+serving programs carry the layer-stacked pool leaves through their layer
+scan and write and gather each layer's rows in place (``models/serve.py``).
 
 Reuse contract (vLLM-style; copy-on-write reduces to the block boundary):
 
