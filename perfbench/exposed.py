@@ -42,7 +42,7 @@ def main(argv=None) -> int:
     sys.path.insert(0, os.path.join(ROOT, "src"))
     import jax
 
-    from pbench import harness, layers, manifest, serving, spans, trace, work
+    from pbench import harness, layers, manifest, serving, spans, trace
     cell = manifest.load_cell(args.workload, ROOT)
     devices = harness.find_devices(cell.chips, harness.load_peaks())
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
@@ -55,7 +55,8 @@ def main(argv=None) -> int:
                                               f"*.{suffix}.py"))):
         name = os.path.basename(path)[:-3]
         readers[name] = manifest.load_module(path, name)
-    dims = work.Dims.of(cell.config["model"])
+    counts = manifest.load_counts(cell)
+    dims = counts.Dims.of(cell.config["model"])
     for i, seed in enumerate(int(s) for s in args.seeds.split(",")):
         server = serving.build_server(cell, seed)
         rec = serving.Recorder()
@@ -72,7 +73,7 @@ def main(argv=None) -> int:
         del server
         reduced = spans.extend(trace.reduce(events), events)
         run = layers.RunData(cell, dims, peak, len(devices), rec.calls,
-                             (out.tracer.t0, out.tracer.t1), reduced)
+                             (out.tracer.t0, out.tracer.t1), reduced, counts)
         by_program = spans.attribute(reduced)
         print(json.dumps({
             "workload": cell.name, "seed": seed,

@@ -9,7 +9,7 @@ import tempfile
 import time
 from typing import Dict, Optional
 
-from pbench import check, layers, serving, trace, training, work
+from pbench import check, layers, manifest, serving, trace, training
 
 PEAKS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "peaks.json")
@@ -108,14 +108,15 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, devices,
     result: Dict = {"correct": False, "attempted": len(out.counted),
                     "failed": out.failed}
     if traced:
-        dims = work.Dims.of(cell.config["model"])
+        counts = manifest.load_counts(cell)
+        dims = counts.Dims.of(cell.config["model"])
         window = (out.tracer.t0, out.tracer.t1)
         if train:
             run = layers.TrainData(cell, dims, peak, len(devices), steps,
-                                   window, reduced)
+                                   window, reduced, counts)
         else:
             run = layers.RunData(cell, dims, peak, len(devices), rec.calls,
-                                 window, reduced)
+                                 window, reduced, counts)
         metrics = {}
         for m in cell.per_layer:
             v = cell.readers[m["name"]].read(run)

@@ -2,9 +2,11 @@
 
 A reader is a file ``metrics/<name>.py`` with ``read(run) -> float | None``.
 ``run`` is a ``RunData``: the host's dispatch records, the reduced trace and
-the work counts.  A reader that finds nothing to read returns None, and the
-metric is left out of the result line; a share of a roofline or of a peak is
-never reported as 0 for want of data.
+the configuration's work counts (``counts``: ``pbench/work.py`` or the
+module its file names, ``manifest.load_counts``).  A reader that finds
+nothing to read returns None, and the metric is left out of the result
+line; a share of a roofline or of a peak is never reported as 0 for want of
+data.
 """
 from __future__ import annotations
 
@@ -12,7 +14,7 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional
 
 from pbench import work
-from pbench.trace import kernel_times, program_times
+from pbench.trace import collective, kernel_times, program_times, union
 
 
 @dataclass
@@ -24,6 +26,7 @@ class RunData:
     calls: List                # serving.Call records of the whole run
     window: Optional[tuple]    # traced window (perf_counter seconds)
     reduced: Optional[Dict]    # trace.reduce(...) of that window
+    counts: object = work      # the module that counts the work
 
     def traced(self, kind: Optional[str] = None) -> List:
         if self.window is None:
@@ -44,9 +47,9 @@ class RunData:
 
     def work(self, call) -> work.Work:
         if call.kind == "decode":
-            return work.decode_work(self.dims, call.info)
+            return self.counts.decode_work(self.dims, call.info)
         off, length, final = call.info
-        return work.chunk_work(self.dims, off, length, final)
+        return self.counts.chunk_work(self.dims, off, length, final)
 
 
 def host_ms(run: RunData, kind: str) -> Optional[float]:
@@ -68,8 +71,10 @@ def device_ms(run: RunData, kind: str) -> Optional[float]:
 
 def roofline_pct(run: RunData, kind: str, bound: str = "max"
                  ) -> Optional[float]:
-    """Least time over device time, summed over the executions of
-    ``kind``: ``bound="max"`` takes the larger of the FLOP and byte roofs,
+    """Each chip's share of the least time over its device time, summed
+    over the executions of ``kind``: the whole step's least time on one
+    chip's peaks, over the chips, against the first chip's time.
+    ``bound="max"`` takes the larger of the FLOP and byte roofs,
     ``"bytes"`` the HBM roof alone."""
     times = run.device_times().get(kind)
     if not times:
@@ -80,7 +85,7 @@ def roofline_pct(run: RunData, kind: str, bound: str = "max"
     for c in calls:
         w = run.work(c)
         least += w.bytes / pb if bound == "bytes" else w.least_s(pf, pb)
-    return 100.0 * least / sum(times)
+    return 100.0 * least / run.chips / sum(times)
 
 
 def mfu_pct(run: RunData) -> Optional[float]:
@@ -100,6 +105,68 @@ def idle_pct(run: RunData) -> Optional[float]:
     return 100.0 * (1.0 - run.reduced["busy_s"] / run.reduced["window_s"])
 
 
+def _collective_intervals(ops, lo: int, hi: int) -> List[tuple]:
+    """The intervals in which a collective runs among one device's leaf
+    ops: a synchronous one for its own duration, an asynchronous one from
+    its start to its done (the window's edge for a half cut off)."""
+    out, pending = [], {}
+    for name, s, e in sorted(ops, key=lambda op: op[1]):
+        kind = collective(name)
+        if kind is None:
+            continue
+        op, phase = kind
+        if phase == "start":
+            pending.setdefault(op, []).append(s)
+        elif phase == "done":
+            starts = pending.get(op)
+            out.append((starts.pop(0) if starts else lo, e))
+        else:
+            out.append((s, e))
+    out += [(s, hi) for starts in pending.values() for s in starts]
+    return out
+
+
+def _minus(a: List[tuple], b: List[tuple]) -> List[tuple]:
+    """The parts of the disjoint sorted intervals ``a`` that no interval
+    of the disjoint sorted ``b`` covers."""
+    out, j = [], 0
+    for s, e in a:
+        while j < len(b) and b[j][1] <= s:
+            j += 1
+        k = j
+        while k < len(b) and b[k][0] < e:
+            if b[k][0] > s:
+                out.append((s, b[k][0]))
+            s = max(s, b[k][1])
+            k += 1
+        if s < e:
+            out.append((s, e))
+    return out
+
+
+def collective_pct(run, exposed: bool = False) -> Optional[float]:
+    """Share of the traced window in which a collective runs on a device,
+    averaged over the devices; with ``exposed``, only the part of it in
+    which no other operation runs on that device.  None where the window
+    holds no collective."""
+    reduced = run.reduced
+    if reduced is None or not reduced.get("leaf_ops"):
+        return None
+    lo, hi = reduced["window"]
+    ns, found = 0, False
+    for ops in reduced["leaf_ops"].values():
+        coll = union(_collective_intervals(ops, lo, hi))
+        found = found or bool(coll)
+        if exposed:
+            coll = _minus(coll, union([(s, e) for n, s, e in ops
+                                       if collective(n) is None]))
+        ns += sum(e - s for s, e in coll)
+    if not found:
+        return None
+    return 100.0 * ns / (1e9 * reduced["window_s"]
+                         * len(reduced["leaf_ops"]))
+
+
 @dataclass
 class TrainData:
     """What a training cell's readers read: the steps of the whole run on
@@ -111,6 +178,7 @@ class TrainData:
     steps: List                # training.Step records of the window
     window: Optional[tuple]
     reduced: Optional[Dict]
+    counts: object = work
 
     @property
     def batch(self) -> int:
@@ -136,7 +204,8 @@ def train_mfu_pct(run: TrainData) -> Optional[float]:
     steps = run.traced()
     if not steps or run.reduced is None:
         return None
-    flops = len(steps) * work.train_step_flops(run.dims, run.batch, run.seq)
+    flops = len(steps) * run.counts.train_step_flops(run.dims, run.batch,
+                                                    run.seq)
     return 100.0 * flops / (run.reduced["window_s"] * run.chips
                             * run.peak["bf16_flops_per_s"])
 
@@ -147,7 +216,8 @@ def kernel_roofline_pct(run: TrainData, kernel: str) -> Optional[float]:
     the trace holds another number of calls than the steps make."""
     steps = run.traced()
     times = run.kernel_times().get(kernel)
-    calls = work.seam_calls(run.dims, run.batch, run.seq, run.chips)[kernel]
+    calls = run.counts.seam_calls(run.dims, run.batch, run.seq,
+                                 run.chips)[kernel]
     if not steps or not times or len(times) != len(steps) * len(calls):
         return None
     pf, pb = run.peak["bf16_flops_per_s"], run.peak["hbm_bytes_per_s"]

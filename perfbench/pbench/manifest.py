@@ -7,6 +7,8 @@ per-layer metric is a file of its own:
 * ``traffic/<traffic>.json``  -- the mix's parameters
 * ``metrics/<metric>.py``     -- a reader exposing ``read(run) -> float|None``
 * ``references/<name>.py``    -- a configuration's plain reference
+* ``counts/<name>.py``        -- a configuration's work counts, named by
+  its file's ``work`` key (``pbench/work.py`` where it has none)
 
 so a later cell, mix or metric is added with new files and manifest entries
 only.
@@ -115,3 +117,16 @@ def load_reference(cell: Cell):
     ref = cell.config["reference"]
     return load_module(os.path.join(cell.bench_dir, "references",
                                     ref + ".py"), ref)
+
+
+def load_counts(cell: Cell):
+    """The module that counts the work of the cell's configuration: the
+    one its file names under ``work``, else ``pbench/work.py``.  Either
+    exposes ``Dims.of``, ``decode_work``, ``chunk_work`` and, for training,
+    ``train_step_flops`` and ``seam_calls``."""
+    name = cell.config.get("work")
+    if name is None:
+        from pbench import work
+        return work
+    return load_module(os.path.join(cell.bench_dir, "counts", name + ".py"),
+                       "counts_" + name)

@@ -109,24 +109,99 @@ class Recorder:
         server.begin_admission = begin_admission
 
 
+#: ``ModelConfig`` field <- the published ``config.json`` key that states
+#: it, in every file
+DIRECT = (("num_layers", "num_hidden_layers"), ("d_model", "hidden_size"),
+          ("num_heads", "num_attention_heads"),
+          ("num_kv_heads", "num_key_value_heads"),
+          ("d_ff", "intermediate_size"), ("vocab_size", "vocab_size"),
+          ("norm_eps", "rms_norm_eps"), ("rope_theta", "rope_theta"),
+          ("tie_embeddings", "tie_word_embeddings"))
+#: latent attention: each key states the ``mla`` field of its own name
+MLA_KEYS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+            "qk_rope_head_dim", "v_head_dim")
+#: routed experts: the keys that give a model experts, one of the first two
+#: counting them
+EXPERT_KEYS = ("n_routed_experts", "num_local_experts", "num_experts_per_tok",
+               "moe_intermediate_size", "n_shared_experts",
+               "first_k_dense_replace")
+
+
+def expected_fields(conf: Dict) -> Dict:
+    """The ``ModelConfig`` fields that the configuration file ``conf``
+    states, by name (a dotted name for a field of ``moe`` or ``mla``).
+    The pattern is implied by the keys the file has: latent attention
+    with the MLA keys, routed experts with the expert keys, otherwise
+    softmax attention, a dense FFN and no experts.  A field read from a key
+    that the file lists under ``reduced`` is not stated."""
+    m = conf["model"]
+    latent = any(k in m for k in MLA_KEYS)
+    experts = any(k in m for k in EXPERT_KEYS)
+    want = {f: (lambda k=k: m[k], (k,)) for f, k in DIRECT}
+    want["rope_style"] = (lambda: "rope", ())
+    want["pattern"] = (lambda: (("mla" if latent else "attn",
+                                 "moe" if experts else "ffn"),), ())
+    want["qkv_bias"] = (lambda: m.get("attention_bias", False),
+                        ("attention_bias",))
+    if latent:
+        want.update({"mla." + k: (lambda k=k: m[k], (k,)) for k in MLA_KEYS})
+    else:
+        want["resolved_head_dim"] = (
+            lambda: m.get("head_dim")
+            or m["hidden_size"] // m["num_attention_heads"],
+            ("head_dim", "hidden_size", "num_attention_heads"))
+    if experts:
+        shared = m.get("n_shared_experts") or 0
+        want.update({
+            "moe.num_experts": (
+                lambda: m.get("n_routed_experts", m.get("num_local_experts")),
+                EXPERT_KEYS[:2]),
+            "moe.top_k": (lambda: m["num_experts_per_tok"],
+                          ("num_experts_per_tok",)),
+            "moe.expert_ffn": (
+                lambda: m.get("moe_intermediate_size",
+                              m["intermediate_size"]),
+                ("moe_intermediate_size", "intermediate_size")),
+            # each shared expert is as wide as a routed one
+            "moe.num_shared_experts": (lambda: shared, ("n_shared_experts",)),
+            "moe.shared_ffn": (
+                lambda: m["moe_intermediate_size"] if shared else 0,
+                ("n_shared_experts", "moe_intermediate_size")),
+            "leading_dense_layers": (lambda: m.get("first_k_dense_replace", 0),
+                                     ("first_k_dense_replace",))})
+    else:
+        want["moe"] = (lambda: None, ())
+    reduced = set(conf.get("reduced", ()))
+    try:
+        return {f: value() for f, (value, keys) in want.items()
+                if not reduced.intersection(keys)}
+    except KeyError as e:
+        raise ValueError(f"{conf['name']}.json states no {e.args[0]}, which "
+                         f"the program's check reads") from None
+
+
+def _field(cfg, name: str):
+    for part in name.split("."):
+        cfg = getattr(cfg, part, None)
+    return cfg
+
+
+def _override(cfg, overrides: Dict):
+    """``cfg`` with the file's overrides; ``moe`` and ``mla`` take a dict
+    of the fields that change."""
+    return dataclasses.replace(cfg, **{
+        k: dataclasses.replace(getattr(cfg, k), **v) if isinstance(v, dict)
+        else v for k, v in overrides.items()})
+
+
 def model_config(cell):
     """The program's ModelConfig for this configuration file, checked
     against the sizes the file states."""
     from repro.configs.base import get_config
     prog = cell.config["program"]
-    cfg = dataclasses.replace(get_config(prog["arch"]),
-                              **prog.get("overrides", {}))
-    m = cell.config["model"]
-    want = {"num_layers": m["num_hidden_layers"], "d_model": m["hidden_size"],
-            "num_heads": m["num_attention_heads"],
-            "num_kv_heads": m["num_key_value_heads"],
-            "d_ff": m["intermediate_size"], "vocab_size": m["vocab_size"],
-            "resolved_head_dim": m.get("head_dim") or
-            m["hidden_size"] // m["num_attention_heads"],
-            "norm_eps": m["rms_norm_eps"], "rope_theta": m["rope_theta"],
-            "tie_embeddings": m["tie_word_embeddings"], "rope_style": "rope",
-            "pattern": (("attn", "ffn"),), "moe": None, "qkv_bias": False}
-    got = {k: getattr(cfg, k) for k in want}
+    cfg = _override(get_config(prog["arch"]), prog.get("overrides", {}))
+    want = expected_fields(cell.config)
+    got = {k: _field(cfg, k) for k in want}
     bad = {k: (got[k], v) for k, v in want.items() if got[k] != v}
     if bad:
         raise ValueError(f"the program's {prog['arch']} differs from "

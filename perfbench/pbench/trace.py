@@ -143,6 +143,28 @@ def seam_kernel(name: str) -> Optional[str]:
     return None
 
 
+#: the collective operations; an asynchronous one runs from its ``-start``
+#: to its ``-done``
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+               "collective-permute", "all-to-all")
+#: a collective's opcode: after the result type and before its operands
+#: (``%psum.3 = bf16[16] all-reduce(...)``), last in an ``op_label``, or
+#: the instruction's own name where XLA named it by its opcode; an operand
+#: (``fusion(%all-reduce.2)``) is none
+_COLLECTIVE = re.compile(r"(?:^%|\s)(" + "|".join(COLLECTIVES)
+                         + r")(-start|-done)?(?=[(.\s]|$)")
+
+
+def collective(name: str) -> Optional[Tuple[str, str]]:
+    """``(operation, phase)`` of a device op that is a collective, the
+    phase ``"start"``, ``"done"`` or ``""`` for a synchronous one; None for
+    any other op."""
+    hit = _COLLECTIVE.search(name)
+    if not hit:
+        return None
+    return hit.group(1), (hit.group(2) or "-")[1:]
+
+
 def kernel_times(reduced: Dict) -> Dict[str, List[float]]:
     """Seconds of each seam kernel execution on the first device in the
     window, by kernel."""
@@ -205,6 +227,7 @@ def reduce(events: Dict, top: int = 10) -> Dict:
             gaps[name] = gaps.get(name, 0.0) + s / len(busy)
     by_time = lambda kv: -kv[1]
     return {
+        "window": (lo, hi),
         "window_s": (hi - lo) / 1e9,
         "busy_s": sum(busy.values()) / len(busy),
         "busy_by_device": busy,

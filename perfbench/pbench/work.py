@@ -5,8 +5,10 @@ zero-padded weight columns or a gathered ``max_seq`` view of the cache cost
 the program time but add nothing here, so waste shows as a lower share of a
 roofline, and removing it can raise the share but never past 100%.
 
-Dense decoder with GQA attention and a SwiGLU FFN, tied embeddings, bf16
-weights and K/V.  A multiply-add is two FLOPs.
+Dense decoder with GQA attention and a SwiGLU FFN, bf16 weights and K/V.
+A multiply-add is two FLOPs.  This is the work count of every
+configuration whose file names no ``work`` module of its own
+(``counts/<name>.py``, which exposes the same functions).
 """
 from __future__ import annotations
 
@@ -25,6 +27,7 @@ class Dims:
     head_dim: int
     d_ff: int
     vocab: int
+    tied: bool = True          # the LM head is the embedding
 
     @staticmethod
     def of(model: Dict) -> "Dims":
@@ -34,7 +37,8 @@ class Dims:
                     kv_heads=model["num_key_value_heads"],
                     head_dim=model.get("head_dim") or d // h,
                     d_ff=model["intermediate_size"],
-                    vocab=model["vocab_size"])
+                    vocab=model["vocab_size"],
+                    tied=model["tie_word_embeddings"])
 
     # ---- parameters --------------------------------------------------------
     @property
@@ -47,10 +51,11 @@ class Dims:
 
     @property
     def params(self) -> int:
-        """Every parameter: layers (with their two norms), the tied
-        embedding and the final norm."""
+        """Every parameter: layers (with their two norms), the embedding,
+        the LM head where it is not the embedding, and the final norm."""
+        embeddings = (1 if self.tied else 2) * self.vocab * self.d_model
         return (self.layers * (self.layer_matmul_params + 2 * self.d_model)
-                + self.vocab * self.d_model + self.d_model)
+                + embeddings + self.d_model)
 
     @property
     def layer_weight_bytes(self) -> int:
@@ -59,7 +64,9 @@ class Dims:
 
     @property
     def head_weight_bytes(self) -> int:
-        """The tied embedding read whole by the LM head, and the final norm."""
+        """The LM head's weight read whole, and the final norm; a decode
+        step or a chunk reads only its tokens' rows of a separate
+        embedding, so tied or not, the head's bytes are the same."""
         return BF16 * (self.vocab * self.d_model + self.d_model)
 
     @property

@@ -2,6 +2,7 @@
 import dataclasses
 import json
 import os
+import shutil
 
 import pytest
 
@@ -103,3 +104,54 @@ def test_seam_kernels_count_unpadded_shards():
     assert ag.bytes == 2 * (m * 2304 + 2304 * 1728 + m * 1728)
     rs = work.gemm_rs_work(m, 576, 2304, tp)
     assert rs.bytes == 2 * (m * 576 + 576 * 2304 + m // tp * 2304)
+
+
+def test_an_untied_head_is_counted_once_more_and_costs_a_step_the_same():
+    tied = _model("phi4_mini")["model"]
+    d, u = work.Dims.of(tied), work.Dims.of(dict(tied,
+                                                 tie_word_embeddings=False))
+    assert u.params - d.params == d.vocab * d.d_model
+    assert u.head_weight_bytes == d.head_weight_bytes
+    assert work.decode_work(u, [5, 70]) == work.decode_work(d, [5, 70])
+    assert work.chunk_work(u, 0, 256, True) == work.chunk_work(d, 0, 256, True)
+
+
+def test_a_configuration_without_counts_of_its_own_takes_pbench_work():
+    from pbench import manifest
+    cell = manifest.load_cell("minicpm_2b.chat", pbench_tiny.REPO)
+    assert manifest.load_counts(cell) is work
+
+
+COUNTS = '''"""Counts that mark what they counted."""
+from pbench.work import Dims, Work, chunk_work  # noqa: F401
+
+
+def decode_work(dims, positions):
+    return Work(flops=1.0, bytes=float(len(positions)))
+'''
+
+
+def test_a_named_counts_module_is_loaded_and_counts_the_steps(tmp_path):
+    """A configuration file names its counts (``"work": "<name>"``); the
+    module under ``counts/`` is found by that name, and the readers'
+    ``RunData`` counts each dispatch with it."""
+    from pbench import layers, manifest, serving
+    bench = tmp_path / "perfbench"
+    shutil.copytree(pbench_tiny.BENCH, bench,
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    (bench / "counts").mkdir(exist_ok=True)
+    (bench / "counts" / "marked.py").write_text(COUNTS)
+    conf = _model("minicpm_2b")
+    conf["work"] = "marked"
+    (bench / "configs" / "minicpm_2b.json").write_text(json.dumps(conf))
+    shutil.copy(os.path.join(pbench_tiny.REPO, "BENCHMARK.json"), tmp_path)
+    cell = manifest.load_cell("minicpm_2b.chat", str(tmp_path))
+    counts = manifest.load_counts(cell)
+    assert counts is not work and counts.__doc__.startswith("Counts that")
+    dims = counts.Dims.of(conf["model"])
+    run = layers.RunData(cell, dims, {}, 1, [], None, None, counts)
+    call = serving.Call("decode", 0.0, 1.0, (3, 9, 11), 3)
+    assert run.work(call) == work.Work(1.0, 3.0)
+    # without the key the same call is counted by pbench/work.py
+    plain = layers.RunData(cell, dims, {}, 1, [], None, None)
+    assert plain.work(call) == work.decode_work(dims, (3, 9, 11))
