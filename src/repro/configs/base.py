@@ -35,6 +35,7 @@ class MoEConfig:
     shared_ffn: int = 0              # d_ff of the shared expert path
     capacity_factor: float = 1.25
     router_dtype: str = "float32"
+    norm_topk: bool = True           # renormalise the top-k gates to sum 1
 
 
 @dataclass(frozen=True)
@@ -54,11 +55,22 @@ class RWKVConfig:
 
 @dataclass(frozen=True)
 class MLAConfig:
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536    # None: one uncompressed w_q
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (DeepSeek-V2's ``rope_scaling`` of type yarn)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -84,6 +96,7 @@ class ModelConfig:
     # attention details
     rope_theta: float = 10000.0
     rope_style: str = "rope"         # rope | mrope | none
+    rope_scaling: Optional[YarnConfig] = None
     qkv_bias: bool = False
     tie_embeddings: bool = False
     norm_eps: float = 1e-5
@@ -199,6 +212,7 @@ ARCH_IDS: List[str] = [
     "musicgen_medium",
     "qwen2_vl_72b",
     "rwkv6_3b",
+    "deepseek_v2_lite",
 ]
 
 # the paper's own eval model (GPT-3 175B GEMM shapes come from this config)
@@ -243,7 +257,8 @@ def shrink(cfg: ModelConfig, **overrides: Any) -> ModelConfig:
             cfg.moe, num_experts=4, top_k=min(cfg.moe.top_k, 2), expert_ffn=128,
             shared_ffn=128 if cfg.moe.shared_ffn else 0)
     if cfg.mla is not None:
-        small["mla"] = MLAConfig(q_lora_rank=64, kv_lora_rank=32,
+        q_lora = None if cfg.mla.q_lora_rank is None else 64
+        small["mla"] = MLAConfig(q_lora_rank=q_lora, kv_lora_rank=32,
                                  qk_nope_head_dim=32, qk_rope_head_dim=16,
                                  v_head_dim=32)
     if cfg.rwkv is not None:

@@ -24,7 +24,8 @@ from jax import lax
 from repro.configs.base import ModelConfig
 from repro.core import overlap
 from repro.models import layers
-from repro.parallel.sharding import TPContext, pad_heads, pad_kv_heads
+from repro.parallel.sharding import (TPContext, ceil_mult, pad_heads,
+                                     pad_kv_heads)
 
 Array = jax.Array
 
@@ -161,8 +162,8 @@ def gqa_train(p: Dict, x: Array, ctx: TPContext, cfg: ModelConfig,
         q = layers.apply_mrope(q, p3, cfg.rope_theta)
         k = layers.apply_mrope(k, p3, cfg.rope_theta)
     elif cfg.rope_style == "rope":
-        q = layers.apply_rope(q, pos, cfg.rope_theta)
-        k = layers.apply_rope(k, pos, cfg.rope_theta)
+        q = layers.apply_rope(q, pos, cfg.rope_theta, cfg.rope_scaling)
+        k = layers.apply_rope(k, pos, cfg.rope_theta, cfg.rope_scaling)
 
     if ctx.use_kernels:
         # fused flash kernel: K/V stream in bf16 once per q-row block, no
@@ -204,8 +205,8 @@ def gqa_decode(p: Dict, x: Array, cache: Dict, pos: Array, ctx: TPContext,
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     pb = pos[:, None]                                    # [B, 1] per-row RoPE
     if cfg.rope_style in ("rope", "mrope"):
-        q = layers.apply_rope(q, pb, cfg.rope_theta)
-        k = layers.apply_rope(k, pb, cfg.rope_theta)
+        q = layers.apply_rope(q, pb, cfg.rope_theta, cfg.rope_scaling)
+        k = layers.apply_rope(k, pb, cfg.rope_theta, cfg.rope_scaling)
 
     ck = layers.cache_update_rows(cache["k"], k, pos)
     cv = layers.cache_update_rows(cache["v"], v, pos)
@@ -254,8 +255,8 @@ def gqa_decode_paged(p: Dict, x: Array, cache: Dict, bt: Array, pos: Array,
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     pb = pos[:, None]
     if cfg.rope_style in ("rope", "mrope"):
-        q = layers.apply_rope(q, pb, cfg.rope_theta)
-        k = layers.apply_rope(k, pb, cfg.rope_theta)
+        q = layers.apply_rope(q, pb, cfg.rope_theta, cfg.rope_scaling)
+        k = layers.apply_rope(k, pb, cfg.rope_theta, cfg.rope_scaling)
 
     ck = layers.pool_update_rows(cache["k"], k, bt, pos, layer=layer)
     cv = layers.pool_update_rows(cache["v"], v, bt, pos, layer=layer)
@@ -309,8 +310,8 @@ def gqa_prefill_chunk(p: Dict, x: Array, cache: Dict, bt: Array, off,
     qpos = off + jnp.arange(c_len, dtype=jnp.int32)       # absolute positions
     posb = jnp.broadcast_to(qpos, (b, c_len))
     if cfg.rope_style in ("rope", "mrope"):
-        q = layers.apply_rope(q, posb, cfg.rope_theta)
-        k = layers.apply_rope(k, posb, cfg.rope_theta)
+        q = layers.apply_rope(q, posb, cfg.rope_theta, cfg.rope_scaling)
+        k = layers.apply_rope(k, posb, cfg.rope_theta, cfg.rope_scaling)
 
     offv = jnp.broadcast_to(off, (b,))
     lenv = jnp.broadcast_to(jnp.asarray(chunk_len, jnp.int32), (b,))
@@ -335,30 +336,18 @@ def gqa_prefill_chunk(p: Dict, x: Array, cache: Dict, bt: Array, off,
     return out, {"k": ck, "v": cv}
 
 
-def gqa_cache_spec(cfg: ModelConfig, tp: int, batch_local: int, s_max: int,
-                   dtype=jnp.bfloat16) -> Dict:
-    d = AttnDims.of(cfg, tp)
-    hkvl = d.hkv_pad // tp
-    shape = (batch_local, s_max, hkvl, d.dh)
-    return {"k": jax.ShapeDtypeStruct(shape, dtype),
-            "v": jax.ShapeDtypeStruct(shape, dtype)}
-
-
 # ---------------------------------------------------------------------------
-# DeepSeek-V3 Multi-head Latent Attention
+# DeepSeek Multi-head Latent Attention (V3: compressed query; V2-Lite: one
+# uncompressed w_q when ``q_lora_rank`` is None)
 # ---------------------------------------------------------------------------
 def init_mla(key, cfg: ModelConfig, tp: int, dtype=jnp.bfloat16) -> Dict:
     m = cfg.mla
     dm = cfg.d_model
     h_pad = pad_heads(cfg.num_heads, tp)
+    dqk = m.qk_nope_head_dim + m.qk_rope_head_dim
     ks = jax.random.split(key, 6)
     std = dm ** -0.5
-    return {
-        "w_dq": (jax.random.normal(ks[0], (dm, m.q_lora_rank)) * std).astype(dtype),
-        "w_uq": (jax.random.normal(
-            ks[1], (m.q_lora_rank,
-                    h_pad * (m.qk_nope_head_dim + m.qk_rope_head_dim)))
-            * m.q_lora_rank ** -0.5).astype(dtype),
+    p = {
         "w_dkv": (jax.random.normal(
             ks[2], (dm, m.kv_lora_rank + m.qk_rope_head_dim)) * std).astype(dtype),
         "w_ukv": (jax.random.normal(
@@ -367,10 +356,126 @@ def init_mla(key, cfg: ModelConfig, tp: int, dtype=jnp.bfloat16) -> Dict:
             * m.kv_lora_rank ** -0.5).astype(dtype),
         "w_o": (jax.random.normal(ks[4], (h_pad * m.v_head_dim, dm))
                 * std).astype(dtype),
-        "q_norm": layers.init_rms_norm(m.q_lora_rank, dtype),
         "kv_norm": layers.init_rms_norm(m.kv_lora_rank, dtype),
         "norm": layers.init_rms_norm(dm, dtype),
     }
+    if m.q_lora_rank is None:
+        p["w_q"] = (jax.random.normal(ks[0], (dm, h_pad * dqk))
+                    * std).astype(dtype)
+        return p
+    p["w_dq"] = (jax.random.normal(ks[0], (dm, m.q_lora_rank))
+                 * std).astype(dtype)
+    p["w_uq"] = (jax.random.normal(ks[1], (m.q_lora_rank, h_pad * dqk))
+                 * m.q_lora_rank ** -0.5).astype(dtype)
+    p["q_norm"] = layers.init_rms_norm(m.q_lora_rank, dtype)
+    return p
+
+
+def _local_proj(a: Array, w: Array) -> Array:
+    return jnp.einsum("bsd,df->bsf", a, w)
+
+
+def _mla_query(p: Dict, h: Array, cfg: ModelConfig, up=_local_proj) -> Array:
+    """Every local head's query ``[B, S, H_l * (nope + rope)]`` from the
+    normed input: one ``w_q`` projection, or the compressed path
+    (``w_dq``, ``q_norm``, ``w_uq``).  ``up`` runs the projection onto the
+    heads: the AllGather seam in training, a local GEMM otherwise."""
+    if "w_q" in p:
+        return up(h, p["w_q"])
+    q_lat = layers.rms_norm(_local_proj(h, p["w_dq"]), p["q_norm"],
+                            cfg.norm_eps)
+    return up(q_lat, p["w_uq"])
+
+
+def _mla_latent(p: Dict, h: Array, cfg: ModelConfig, positions: Array
+                ) -> Tuple[Array, Array]:
+    """The cached latent of each row: the normed ``kv_lora_rank`` part and
+    the shared rope key, rotated at ``positions`` ([B, S])."""
+    r = cfg.mla.kv_lora_rank
+    kv_all = _local_proj(h, p["w_dkv"])
+    kv_lat = layers.rms_norm(kv_all[..., :r], p["kv_norm"], cfg.norm_eps)
+    k_rope = layers.apply_rope(kv_all[..., None, r:], positions,
+                               cfg.rope_theta, cfg.rope_scaling)[..., 0, :]
+    return kv_lat, k_rope
+
+
+def latent_width(cfg: ModelConfig) -> int:
+    """Lanes of a latent cache row: the latent, the shared rope key, and
+    zeros up to a multiple of 128.  The TPU stores a pool leaf whose minor
+    dimension is not a multiple of 128 block-minor (a 64-wide rope leaf of
+    its own, or DeepSeek's 576-wide row), and every program that scatters
+    into or gathers from it then relays the whole layer stack out and
+    back; a 640-wide row stays row-major, for 11% more bytes."""
+    m = cfg.mla
+    return ceil_mult(m.kv_lora_rank + m.qk_rope_head_dim, 128)
+
+
+def _latent_row(kv_lat: Array, k_rope: Array, cfg: ModelConfig) -> Array:
+    """A cache row (``latent_width`` lanes) of each token."""
+    row = jnp.concatenate([kv_lat, k_rope.astype(kv_lat.dtype)], axis=-1)
+    pad = latent_width(cfg) - row.shape[-1]
+    return jnp.pad(row, [(0, 0)] * (row.ndim - 1) + [(0, pad)])
+
+
+def _split_latent(rows: Array, cfg: ModelConfig) -> Tuple[Array, Array]:
+    """The latent and the rope key of cache rows."""
+    m = cfg.mla
+    return (rows[..., :m.kv_lora_rank],
+            rows[..., m.kv_lora_rank:m.kv_lora_rank + m.qk_rope_head_dim])
+
+
+def _mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale: ``(nope + rope)^-0.5``, times YaRN's temperature
+    factor squared where the configuration has one."""
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= layers.yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+def _absorbed_query(p: Dict, h: Array, cfg: ModelConfig, positions: Array,
+                    hl: int) -> Tuple[Array, Array, Array]:
+    """The absorbed-form query: ``q_nope . W_uk`` per head in the latent
+    space ([B, S, H_l, rank], fp32), the rotated rope query, and ``W_uv``
+    ([rank, H_l, v]) for after the weighted latent sum."""
+    m = cfg.mla
+    b, s = h.shape[:2]
+    q = _mla_query(p, h, cfg).reshape(
+        b, s, hl, m.qk_nope_head_dim + m.qk_rope_head_dim)
+    q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
+    q_rope = layers.apply_rope(q_rope, positions, cfg.rope_theta,
+                               cfg.rope_scaling)
+    w_ukv = p["w_ukv"].reshape(m.kv_lora_rank, hl,
+                               m.qk_nope_head_dim + m.v_head_dim)
+    w_uk = w_ukv[:, :, :m.qk_nope_head_dim]             # [r, h, dn]
+    w_uv = w_ukv[:, :, m.qk_nope_head_dim:]             # [r, h, dv]
+    q_eff = jnp.einsum("bqhd,rhd->bqhr", q_nope.astype(jnp.float32),
+                       w_uk.astype(jnp.float32))
+    return q_eff, q_rope, w_uv
+
+
+def _latent_attention(q_eff: Array, q_rope: Array, c: Array, kr: Array,
+                      valid: Array, scale: float) -> Array:
+    """Scores of the absorbed queries against every cached latent row
+    (``c``: [B, S, rank], ``kr``: [B, S, rope]) masked by ``valid``
+    (broadcast to [B, H, Q, S]); returns the weighted latent sum
+    [B, Q, H, rank] in fp32."""
+    scores = (jnp.einsum("bqhr,bsr->bhqs", q_eff, c.astype(jnp.float32))
+              + jnp.einsum("bqhd,bsd->bhqs", q_rope.astype(jnp.float32),
+                           kr.astype(jnp.float32))) * scale
+    w = jax.nn.softmax(jnp.where(valid, scores, -1e30), axis=-1)
+    return jnp.einsum("bhqs,bsr->bqhr", w, c.astype(jnp.float32))
+
+
+def _latent_out(ctx_lat: Array, w_uv: Array, w_o: Array, dtype,
+                ctx: TPContext, seam: str) -> Array:
+    """``W_uv`` after the weighted latent sum, then the output seam."""
+    b, s, hl = ctx_lat.shape[:3]
+    attn = jnp.einsum("bqhr,rhd->bqhd", ctx_lat, w_uv.astype(jnp.float32))
+    attn = attn.reshape(b, s, hl * w_uv.shape[-1]).astype(dtype)
+    return ctx.op(seam)(attn, w_o)
 
 
 def mla_train(p: Dict, x: Array, ctx: TPContext, cfg: ModelConfig,
@@ -384,23 +489,15 @@ def mla_train(p: Dict, x: Array, ctx: TPContext, cfg: ModelConfig,
     dqk = m.qk_nope_head_dim + m.qk_rope_head_dim
 
     h = layers.rms_norm(x, p["norm"], cfg.norm_eps)
-    # latent down-projections: replicated weights, sequence-local compute
-    q_lat = layers.rms_norm(jnp.einsum("bsd,dr->bsr", h, p["w_dq"]),
-                            p["q_norm"], cfg.norm_eps)
-    kv_all = jnp.einsum("bsd,dr->bsr", h, p["w_dkv"])
-    kv_lat = layers.rms_norm(kv_all[..., :m.kv_lora_rank], p["kv_norm"],
-                             cfg.norm_eps)
-    k_rope_s = kv_all[..., m.kv_lora_rank:]             # [B, S/TP, dr] shared
-
-    # RoPE on the shard (positions known locally), then gather sequence
-    pos_loc = layers.seq_positions(b, s_loc, ctx)
-    k_rope_s = layers.apply_rope(k_rope_s[:, :, None, :], pos_loc,
-                                 cfg.rope_theta)[:, :, 0, :]
+    # latent down-projection: replicated weights, sequence-local compute;
+    # RoPE on the shard (positions known locally)
+    kv_lat, k_rope_s = _mla_latent(p, h, cfg,
+                                   layers.seq_positions(b, s_loc, ctx))
 
     # head up-projections: the FLUX AllGather-GEMM seams (distinct input
     # latents -> no gather sharing between them)
     ag_op = ctx.op("attn_ag")
-    q = ag_op(q_lat, p["w_uq"]).reshape(b, s, hl, dqk)
+    q = _mla_query(p, h, cfg, up=ag_op).reshape(b, s, hl, dqk)
     kv = ag_op(kv_lat, p["w_ukv"])
     kv = kv.reshape(b, s, hl, m.qk_nope_head_dim + m.v_head_dim)
     k_nope, v = jnp.split(kv, [m.qk_nope_head_dim], axis=-1)
@@ -410,21 +507,23 @@ def mla_train(p: Dict, x: Array, ctx: TPContext, cfg: ModelConfig,
     k_rope = ctx.gather_seq(k_rope_s, "attn_ag")
     pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = layers.apply_rope(q_rope, pos, cfg.rope_theta)
+    q_rope = layers.apply_rope(q_rope, pos, cfg.rope_theta, cfg.rope_scaling)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
                                   (b, s, hl, m.qk_rope_head_dim))], axis=-1)
 
-    attn = blocked_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                             v.transpose(0, 2, 1, 3),
-                             scale=dqk ** -0.5)
+    with jax.named_scope("mla_attn"):
+        attn = blocked_attention(q.transpose(0, 2, 1, 3),
+                                 k.transpose(0, 2, 1, 3),
+                                 v.transpose(0, 2, 1, 3),
+                                 scale=_mla_scale(cfg))
     attn = attn.transpose(0, 2, 1, 3).reshape(b, s, hl * m.v_head_dim)
     out = ctx.op("attn_rs")(attn, p["w_o"])
     if with_cache:
         c_full = ctx.gather_seq(kv_lat, "attn_ag")
-        return out, {"c": c_full.astype(jnp.bfloat16),
-                     "kr": k_rope.astype(jnp.bfloat16)}
+        return out, {"latent": _latent_row(c_full, k_rope, cfg).astype(
+            jnp.bfloat16)}
     return out
 
 
@@ -434,128 +533,69 @@ def mla_decode(p: Dict, x: Array, cache: Dict, pos: Array, ctx: TPContext,
     (kv_lora_rank + rope) per token — DeepSeek's decode memory win.  The
     nope-scores absorb W_uk into the query; values absorb W_uv after the
     weighted latent sum.  ``pos``: [B] int32 per-row write positions."""
-    m = cfg.mla
-    tp = ctx.tp
-    h_pad = pad_heads(cfg.num_heads, tp)
-    hl = h_pad // tp
+    hl = pad_heads(cfg.num_heads, ctx.tp) // ctx.tp
     b = x.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
+    pb = pos[:, None]                                    # [B, 1] per-row RoPE
 
     h = layers.rms_norm(x, p["norm"], cfg.norm_eps)
-    q_lat = layers.rms_norm(jnp.einsum("bsd,dr->bsr", h, p["w_dq"]),
-                            p["q_norm"], cfg.norm_eps)
-    kv_all = jnp.einsum("bsd,dr->bsr", h, p["w_dkv"])
-    kv_lat = layers.rms_norm(kv_all[..., :m.kv_lora_rank], p["kv_norm"],
-                             cfg.norm_eps)
-    k_rope = kv_all[..., m.kv_lora_rank:]
+    kv_lat, k_rope = _mla_latent(p, h, cfg, pb)
+    q_eff, q_rope, w_uv = _absorbed_query(p, h, cfg, pb, hl)
+    lat = layers.cache_update_rows(cache["latent"],
+                                   _latent_row(kv_lat, k_rope, cfg), pos)
+    c_cache, r_cache = _split_latent(lat, cfg)
 
-    pb = pos[:, None]                                    # [B, 1] per-row RoPE
-    k_rope = layers.apply_rope(k_rope[:, :, None, :], pb,
-                               cfg.rope_theta)[:, :, 0, :]
-
-    dqk = m.qk_nope_head_dim + m.qk_rope_head_dim
-    q = jnp.einsum("bsr,rf->bsf", q_lat, p["w_uq"]).reshape(b, 1, hl, dqk)
-    q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = layers.apply_rope(q_rope, pb, cfg.rope_theta)
-
-    # absorb W_uk: q_eff[b,1,h,r] = q_nope . W_uk[r, h, dn]
-    w_ukv = p["w_ukv"].reshape(m.kv_lora_rank, hl,
-                               m.qk_nope_head_dim + m.v_head_dim)
-    w_uk = w_ukv[:, :, :m.qk_nope_head_dim]             # [r, h, dn]
-    w_uv = w_ukv[:, :, m.qk_nope_head_dim:]             # [r, h, dv]
-    q_eff = jnp.einsum("bohd,rhd->bohr", q_nope.astype(jnp.float32),
-                       w_uk.astype(jnp.float32))
-
-    c_cache = layers.cache_update_rows(cache["c"], kv_lat, pos)
-    r_cache = layers.cache_update_rows(cache["kr"], k_rope, pos)
-
-    if ctx.use_kernels:
-        # fused flash-style pass over the latent cache: ONE streaming read
-        # instead of two + no fp32 score materialization (§Perf cell 3)
-        from repro.kernels.mla_decode import mla_decode_attention
-        ctx_lat = mla_decode_attention(
-            q_eff[:, 0], q_rope[:, 0].astype(jnp.float32), c_cache, r_cache,
-            pos + 1, scale=dqk ** -0.5)[:, None]
-    else:
-        s_max = c_cache.shape[1]
-        scores = (jnp.einsum("bohr,bsr->bhos", q_eff,
-                             c_cache.astype(jnp.float32))
-                  + jnp.einsum("bohd,bsd->bhos", q_rope.astype(jnp.float32),
-                               r_cache.astype(jnp.float32))) * (dqk ** -0.5)
-        valid = (jnp.arange(s_max)[None, :] <= pos[:, None])[:, None, None, :]
-        scores = jnp.where(valid, scores, -1e30)
-        w = jax.nn.softmax(scores, axis=-1)
-        ctx_lat = jnp.einsum("bhos,bsr->bohr", w,
-                             c_cache.astype(jnp.float32))
-    attn = jnp.einsum("bohr,rhd->bohd", ctx_lat, w_uv.astype(jnp.float32))
-    attn = attn.reshape(b, 1, hl * m.v_head_dim).astype(x.dtype)
-    out = ctx.op("decode_ar")(attn, p["w_o"])
-    return out, {"c": c_cache, "kr": r_cache}
+    with jax.named_scope("mla_attn"):
+        if ctx.use_kernels:
+            # fused flash-style pass over the latent cache: ONE streaming
+            # read instead of two + no fp32 score materialization
+            from repro.kernels.mla_decode import mla_decode_attention
+            ctx_lat = mla_decode_attention(
+                q_eff[:, 0], q_rope[:, 0].astype(jnp.float32), c_cache,
+                r_cache, pos + 1, scale=_mla_scale(cfg))[:, None]
+        else:
+            valid = (jnp.arange(c_cache.shape[1])[None, :]
+                     <= pos[:, None])[:, None, None, :]
+            ctx_lat = _latent_attention(q_eff, q_rope, c_cache, r_cache,
+                                        valid, _mla_scale(cfg))
+    out = _latent_out(ctx_lat, w_uv, p["w_o"], x.dtype, ctx, "decode_ar")
+    return out, {"latent": lat}
 
 
 def mla_decode_paged(p: Dict, x: Array, cache: Dict, bt: Array, pos: Array,
                      ctx: TPContext, cfg: ModelConfig, layer=None
                      ) -> Tuple[Array, Dict]:
     """Absorbed-form MLA decode over the paged latent pool.  cache:
-    {c: [N_blocks, bs, rank], kr: [N_blocks, bs, rope_dim]}; bt: [B, P].
+    {latent: [N_blocks, bs, latent_width]}; bt: [B, P].
     The gathered per-row views are shaped like the dense caches, so the
     fused decode kernel path applies unchanged.  ``layer``: as in
     ``gqa_decode_paged``."""
-    m = cfg.mla
-    tp = ctx.tp
-    h_pad = pad_heads(cfg.num_heads, tp)
-    hl = h_pad // tp
+    hl = pad_heads(cfg.num_heads, ctx.tp) // ctx.tp
     b = x.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
+    pb = pos[:, None]
 
     h = layers.rms_norm(x, p["norm"], cfg.norm_eps)
-    q_lat = layers.rms_norm(jnp.einsum("bsd,dr->bsr", h, p["w_dq"]),
-                            p["q_norm"], cfg.norm_eps)
-    kv_all = jnp.einsum("bsd,dr->bsr", h, p["w_dkv"])
-    kv_lat = layers.rms_norm(kv_all[..., :m.kv_lora_rank], p["kv_norm"],
-                             cfg.norm_eps)
-    k_rope = kv_all[..., m.kv_lora_rank:]
+    kv_lat, k_rope = _mla_latent(p, h, cfg, pb)
+    q_eff, q_rope, w_uv = _absorbed_query(p, h, cfg, pb, hl)
+    pool = layers.pool_update_rows(cache["latent"],
+                                   _latent_row(kv_lat, k_rope, cfg), bt,
+                                   pos, layer=layer)
+    cview, rview = _split_latent(layers.pool_view(pool, bt, layer), cfg)
 
-    pb = pos[:, None]
-    k_rope = layers.apply_rope(k_rope[:, :, None, :], pb,
-                               cfg.rope_theta)[:, :, 0, :]
-
-    dqk = m.qk_nope_head_dim + m.qk_rope_head_dim
-    q = jnp.einsum("bsr,rf->bsf", q_lat, p["w_uq"]).reshape(b, 1, hl, dqk)
-    q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = layers.apply_rope(q_rope, pb, cfg.rope_theta)
-
-    w_ukv = p["w_ukv"].reshape(m.kv_lora_rank, hl,
-                               m.qk_nope_head_dim + m.v_head_dim)
-    w_uk = w_ukv[:, :, :m.qk_nope_head_dim]
-    w_uv = w_ukv[:, :, m.qk_nope_head_dim:]
-    q_eff = jnp.einsum("bohd,rhd->bohr", q_nope.astype(jnp.float32),
-                       w_uk.astype(jnp.float32))
-
-    cc = layers.pool_update_rows(cache["c"], kv_lat, bt, pos, layer=layer)
-    cr = layers.pool_update_rows(cache["kr"], k_rope, bt, pos, layer=layer)
-    cview = layers.pool_view(cc, bt, layer)        # [B, P*bs, rank]
-    rview = layers.pool_view(cr, bt, layer)
-
-    if ctx.use_kernels:
-        from repro.kernels.mla_decode import mla_decode_attention
-        ctx_lat = mla_decode_attention(
-            q_eff[:, 0], q_rope[:, 0].astype(jnp.float32), cview, rview,
-            pos + 1, scale=dqk ** -0.5)[:, None]
-    else:
-        s_tot = cview.shape[1]
-        scores = (jnp.einsum("bohr,bsr->bhos", q_eff,
-                             cview.astype(jnp.float32))
-                  + jnp.einsum("bohd,bsd->bhos", q_rope.astype(jnp.float32),
-                               rview.astype(jnp.float32))) * (dqk ** -0.5)
-        valid = (jnp.arange(s_tot)[None, :] <= pos[:, None])[:, None, None, :]
-        scores = jnp.where(valid, scores, -1e30)
-        w = jax.nn.softmax(scores, axis=-1)
-        ctx_lat = jnp.einsum("bhos,bsr->bohr", w, cview.astype(jnp.float32))
-    attn = jnp.einsum("bohr,rhd->bohd", ctx_lat, w_uv.astype(jnp.float32))
-    attn = attn.reshape(b, 1, hl * m.v_head_dim).astype(x.dtype)
-    out = ctx.op("decode_ar")(attn, p["w_o"])
-    return out, {"c": cc, "kr": cr}
+    with jax.named_scope("mla_attn"):
+        if ctx.use_kernels:
+            from repro.kernels.mla_decode import mla_decode_attention
+            ctx_lat = mla_decode_attention(
+                q_eff[:, 0], q_rope[:, 0].astype(jnp.float32), cview, rview,
+                pos + 1, scale=_mla_scale(cfg))[:, None]
+        else:
+            valid = (jnp.arange(cview.shape[1])[None, :]
+                     <= pos[:, None])[:, None, None, :]
+            ctx_lat = _latent_attention(q_eff, q_rope, cview, rview, valid,
+                                        _mla_scale(cfg))
+    out = _latent_out(ctx_lat, w_uv, p["w_o"], x.dtype, ctx, "decode_ar")
+    return out, {"latent": pool}
 
 
 def mla_prefill_chunk(p: Dict, x: Array, cache: Dict, bt: Array, off,
@@ -566,64 +606,27 @@ def mla_prefill_chunk(p: Dict, x: Array, cache: Dict, bt: Array, off,
     identical to the non-absorbed prefill by associativity — q_nope·(W_uk c)
     = (q_nope W_uk)·c, both in fp32).  x: [B, C, D] replicated.
     ``layer``: as in ``gqa_decode_paged``."""
-    m = cfg.mla
-    tp = ctx.tp
-    h_pad = pad_heads(cfg.num_heads, tp)
-    hl = h_pad // tp
+    hl = pad_heads(cfg.num_heads, ctx.tp) // ctx.tp
     b, c_len, _ = x.shape
-
-    h = layers.rms_norm(x, p["norm"], cfg.norm_eps)
-    q_lat = layers.rms_norm(jnp.einsum("bsd,dr->bsr", h, p["w_dq"]),
-                            p["q_norm"], cfg.norm_eps)
-    kv_all = jnp.einsum("bsd,dr->bsr", h, p["w_dkv"])
-    kv_lat = layers.rms_norm(kv_all[..., :m.kv_lora_rank], p["kv_norm"],
-                             cfg.norm_eps)
-    k_rope = kv_all[..., m.kv_lora_rank:]
-
     off = jnp.asarray(off, jnp.int32)
     qpos = off + jnp.arange(c_len, dtype=jnp.int32)
     posb = jnp.broadcast_to(qpos, (b, c_len))
-    k_rope = layers.apply_rope(k_rope[:, :, None, :], posb,
-                               cfg.rope_theta)[:, :, 0, :]
 
-    dqk = m.qk_nope_head_dim + m.qk_rope_head_dim
-    q = jnp.einsum("bsr,rf->bsf", q_lat, p["w_uq"]).reshape(b, c_len, hl, dqk)
-    q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = layers.apply_rope(q_rope, posb, cfg.rope_theta)
-
-    w_ukv = p["w_ukv"].reshape(m.kv_lora_rank, hl,
-                               m.qk_nope_head_dim + m.v_head_dim)
-    w_uk = w_ukv[:, :, :m.qk_nope_head_dim]
-    w_uv = w_ukv[:, :, m.qk_nope_head_dim:]
-    q_eff = jnp.einsum("bqhd,rhd->bqhr", q_nope.astype(jnp.float32),
-                       w_uk.astype(jnp.float32))
+    h = layers.rms_norm(x, p["norm"], cfg.norm_eps)
+    kv_lat, k_rope = _mla_latent(p, h, cfg, posb)
+    q_eff, q_rope, w_uv = _absorbed_query(p, h, cfg, posb, hl)
 
     offv = jnp.broadcast_to(off, (b,))
     lenv = jnp.broadcast_to(jnp.asarray(chunk_len, jnp.int32), (b,))
-    cc = layers.pool_update_rows(cache["c"], kv_lat, bt, offv, valid=lenv,
-                                 layer=layer)
-    cr = layers.pool_update_rows(cache["kr"], k_rope, bt, offv, valid=lenv,
-                                 layer=layer)
-    cview = layers.pool_view(cc, bt, layer)
-    rview = layers.pool_view(cr, bt, layer)
+    pool = layers.pool_update_rows(cache["latent"],
+                                   _latent_row(kv_lat, k_rope, cfg), bt,
+                                   offv, valid=lenv, layer=layer)
+    cview, rview = _split_latent(layers.pool_view(pool, bt, layer), cfg)
 
-    s_tot = cview.shape[1]
-    scores = (jnp.einsum("bqhr,bsr->bhqs", q_eff, cview.astype(jnp.float32))
-              + jnp.einsum("bqhd,bsd->bhqs", q_rope.astype(jnp.float32),
-                           rview.astype(jnp.float32))) * (dqk ** -0.5)
-    valid = (jnp.arange(s_tot)[None, :] <= qpos[:, None])[None, None]
-    scores = jnp.where(valid, scores, -1e30)
-    w = jax.nn.softmax(scores, axis=-1)
-    ctx_lat = jnp.einsum("bhqs,bsr->bqhr", w, cview.astype(jnp.float32))
-    attn = jnp.einsum("bqhr,rhd->bqhd", ctx_lat, w_uv.astype(jnp.float32))
-    attn = attn.reshape(b, c_len, hl * m.v_head_dim).astype(x.dtype)
-    out = ctx.op("attn_rs")(attn, p["w_o"])
-    return out, {"c": cc, "kr": cr}
-
-
-def mla_cache_spec(cfg: ModelConfig, tp: int, batch_local: int, s_max: int,
-                   dtype=jnp.bfloat16) -> Dict:
-    m = cfg.mla
-    return {"c": jax.ShapeDtypeStruct((batch_local, s_max, m.kv_lora_rank), dtype),
-            "kr": jax.ShapeDtypeStruct((batch_local, s_max, m.qk_rope_head_dim),
-                                       dtype)}
+    with jax.named_scope("mla_attn"):
+        valid = (jnp.arange(cview.shape[1])[None, :]
+                 <= qpos[:, None])[None, None]
+        ctx_lat = _latent_attention(q_eff, q_rope, cview, rview, valid,
+                                    _mla_scale(cfg))
+    out = _latent_out(ctx_lat, w_uv, p["w_o"], x.dtype, ctx, "attn_rs")
+    return out, {"latent": pool}

@@ -1,12 +1,15 @@
 """FFN blocks: dense SwiGLU (Megatron TP seams) and expert-parallel MoE.
 
-MoE dispatch is a capacity-bucketed exchange over the EP group (a dedicated
-"ep" axis, the "model" axis, or ("data","model") jointly for DeepSeek-scale
-expert counts).  The whole middle — dispatch a2a, batched per-local-expert
-GEMMs, combine a2a — is ONE ``overlap.FusedOp(kind="a2a")`` seam
-(``ctx.op("moe_a2a")``): ring modes decompose both exchanges into ppermute
-chunks hidden under the chunked expert compute, the FLUX move applied to
-expert parallelism.  The shared-expert path is a regular dense TP FFN.
+Training MoE dispatch is a capacity-bucketed exchange over the EP group (a
+dedicated "ep" axis, the "model" axis, or ("data","model") jointly for
+DeepSeek-scale expert counts).  The whole middle — dispatch a2a, batched
+per-local-expert GEMMs, combine a2a — is ONE ``overlap.FusedOp(kind="a2a")``
+seam (``ctx.op("moe_a2a")``): ring modes decompose both exchanges into
+ppermute chunks hidden under the chunked expert compute, the FLUX move
+applied to expert parallelism.  Serving (``moe_serve``: the decode step and
+the prefill chunk) is dropless: each device runs the experts it holds on
+every assignment routed to them, as grouped GEMMs, and a psum combines.
+The shared-expert path is a regular dense TP FFN.
 """
 from __future__ import annotations
 
@@ -144,7 +147,7 @@ def moe_train(p: Dict, x: Array, ctx: TPContext, cfg: ModelConfig,
     tokens, so an all_to_all over the model axis would dispatch each token
     TP times — instead each rank computes only its LOCAL experts'
     contributions for the full token set and a psum over the EP group
-    combines them (the moe_decode strategy, with training capacity
+    combines them (the moe_serve strategy, with training capacity
     semantics).
 
     CAVEATS (where the two layouts are not interchangeable):
@@ -161,7 +164,7 @@ def moe_train(p: Dict, x: Array, ctx: TPContext, cfg: ModelConfig,
       partials that the DP grad contract (per-data-shard grads, averaged)
       mis-sums — that configuration raises instead of training wrong
       (decode, which is grad-free, keeps the full multi-axis path in
-      ``moe_decode``).
+      ``moe_serve``).
     """
     mc = cfg.moe
     b, s_loc, dm = x.shape
@@ -186,10 +189,7 @@ def moe_train(p: Dict, x: Array, ctx: TPContext, cfg: ModelConfig,
             "(scatter_axis='seq').")
 
     # ---- router (fp32) ------------------------------------------------------
-    logits = jnp.einsum("td,de->te", ht.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, eidx = lax.top_k(probs, mc.top_k)             # [t, k]
-    gate = gate / jnp.clip(jnp.sum(gate, -1, keepdims=True), 1e-9)
+    probs, gate, eidx = route(p, ht, mc)                # gate, eidx: [t, k]
 
     # pad mask of a right-padded prefill batch: pad rows must not count in
     # the router statistics, the capacity cumsum, the dispatch or the combine
@@ -290,25 +290,23 @@ def moe_train(p: Dict, x: Array, ctx: TPContext, cfg: ModelConfig,
     return y, aux.astype(jnp.float32)
 
 
-def moe_decode(p: Dict, x: Array, ctx: TPContext, cfg: ModelConfig,
-               eps: float = 1e-5) -> Array:
-    """Decode MoE.  x: [B, 1, D] REPLICATED over the model axis (decode has
-    no sequence sharding).  Tokens that belong to other data shards of the EP
-    group are brought in by a (tiny) all_gather; every device computes only
-    its LOCAL experts' contributions, and a psum over the EP group combines
-    them — no all_to_all needed at one-token scale."""
+def moe_serve(p: Dict, x: Array, ctx: TPContext, cfg: ModelConfig
+              ) -> Array:
+    """Dropless serving MoE, for the decode step ([B, 1, D]) and the
+    prefill chunk ([1, C, D]).  x is REPLICATED over the model axis;
+    tokens that belong to other data shards of the EP group are brought in
+    by a (tiny) all_gather.  Every device routes all tokens over all
+    experts, runs the experts it holds on every token and weights each
+    output by the token's gate for that expert (``experts_held``: no
+    capacity, so no token is ever dropped), and a psum over the EP group
+    combines the devices' partial outputs.  The shared experts are a dense
+    TP FFN on the decode seam."""
     mc = cfg.moe
-    b = x.shape[0]
-    dm = x.shape[-1]
+    b, s, dm = x.shape
     ep_axes = ctx.ep_axes or ((ctx.axis,) if ctx.axis else ())
-    ep = 1
-    for a in ep_axes:
-        ep = ep * compat.axis_size(a)
-    e = mc.num_experts
-    e_loc = max(e // ep, 1)
 
-    h = layers.rms_norm(x, p["norm"], eps)
-    ht = h.reshape(b, dm)
+    h = layers.rms_norm(x, p["norm"], cfg.norm_eps)
+    ht = h.reshape(b * s, dm)
     # gather tokens across the data portion of the EP group (tokens are
     # already replicated over the model axis)
     gather_axes = tuple(a for a in ep_axes if a != ctx.axis)
@@ -320,43 +318,14 @@ def moe_decode(p: Dict, x: Array, ctx: TPContext, cfg: ModelConfig,
                 ht, a, axis=0, tiled=True)
     t = ht.shape[0]
 
-    logits = jnp.einsum("td,de->te", ht.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, eidx = lax.top_k(probs, mc.top_k)
-    gate = gate / jnp.clip(jnp.sum(gate, -1, keepdims=True), 1e-9)
-
-    # rank of this device inside the EP group -> which experts are local
+    with jax.named_scope("moe_route"):
+        _, gate, eidx = route(p, ht, mc)
+    # rank of this device inside the EP group -> which experts are held
     ep_rank = jnp.zeros((), jnp.int32)
     for a in ep_axes:
         ep_rank = ep_rank * compat.axis_size(a) + lax.axis_index(a)
-    e_start = ep_rank * e_loc
-
-    flat_e = eidx.reshape(-1)
-    local_e = flat_e - e_start
-    is_local = (local_e >= 0) & (local_e < e_loc)
-    local_e = jnp.clip(local_e, 0, e_loc - 1)
-    # statistical capacity (§Perf iteration, deepseek decode): buckets sized
-    # ~8x the mean per-expert load instead of t*k — cuts the batched expert
-    # GEMMs ~e/8-fold.  Overflow probability is a Poisson tail (negligible);
-    # any overflow drops, matching training-time capacity semantics.
-    cap = int(min(t * mc.top_k, max(32, (t * mc.top_k * 8) // e)))
-    src = jnp.repeat(jnp.arange(t), mc.top_k)
-    oh = jax.nn.one_hot(jnp.where(is_local, local_e, e_loc), e_loc + 1,
-                        dtype=jnp.int32)[:, :e_loc]      # [t*k, e_loc]
-    pos = jnp.sum(jnp.cumsum(oh, axis=0) * oh, axis=-1) - 1
-    keep = is_local & (pos >= 0) & (pos < cap)
-    slot = jnp.clip(pos, 0, cap - 1)
-
-    disp = jnp.zeros((e_loc, cap, dm), ht.dtype)
-    disp = disp.at[local_e, slot].add(jnp.where(keep[:, None], ht[src], 0))
-    a1 = jnp.einsum("ecd,edf->ecf", disp, p["w1"])
-    a3 = jnp.einsum("ecd,edf->ecf", disp, p["w3"])
-    out = jnp.einsum("ecf,efd->ecd", jax.nn.silu(a1) * a3, p["w2"])
-
-    vals = out[local_e, slot]
-    vals = jnp.where(keep[:, None], vals, 0)
-    comb = jax.ops.segment_sum(vals * gate.reshape(-1)[:, None], src,
-                               num_segments=t)
+    with jax.named_scope("moe_experts"):
+        comb = experts_held(p, ht, gate, eidx, ep_rank * p["w1"].shape[0])
     with jax.named_scope("seam_moe_combine"):
         for a in ep_axes:
             comb = lax.psum(comb, a)
@@ -368,10 +337,55 @@ def moe_decode(p: Dict, x: Array, ctx: TPContext, cfg: ModelConfig,
         for a in reversed(gather_axes):
             blk = blk // compat.axis_size(a)
             my_off = my_off + lax.axis_index(a) * blk
-        comb = lax.dynamic_slice_in_dim(comb, my_off, b, axis=0)
-    y = comb.reshape(b, 1, dm).astype(x.dtype)
+        comb = lax.dynamic_slice_in_dim(comb, my_off, b * s, axis=0)
+    y = comb.reshape(b, s, dm).astype(x.dtype)
 
     if mc.num_shared_experts:
         sh = {"norm": p["norm"], **{k: v for k, v in p["shared"].items()}}
-        y = y + ffn_decode(sh, x, ctx, eps)
+        y = y + ffn_decode(sh, x, ctx, cfg.norm_eps)
     return y
+
+
+def route(p: Dict, ht: Array, mc: MoEConfig) -> Tuple[Array, Array, Array]:
+    """Softmax routing of tokens ``ht`` [t, D] in fp32: (probabilities
+    [t, E], the top-k gates [t, k] — renormalised to sum 1 when
+    ``mc.norm_topk`` — and their experts [t, k])."""
+    logits = jnp.einsum("td,de->te", ht.astype(jnp.float32), p["router"],
+                        precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate, eidx = lax.top_k(probs, mc.top_k)
+    if mc.norm_topk:
+        gate = gate / jnp.clip(jnp.sum(gate, -1, keepdims=True), 1e-9)
+    return probs, gate, eidx
+
+
+def experts_held(p: Dict, ht: Array, gate: Array, eidx: Array,
+                 e_start) -> Array:
+    """The part of the routed-expert output that the experts held here (global
+    ids ``e_start`` .. ``e_start + E_l - 1``, the local stacks of
+    ``p["w1"]``/``w3``/``w2``) give: [t, D] fp32, each (token, expert)
+    assignment's SwiGLU output weighted by its gate.  Every held expert runs
+    on every token, as batched GEMMs that read the layer's slice of the
+    scanned expert stack in place; each assignment then takes its expert's row
+    and the rows are combined as ``moe_train`` combines them, so no assignment
+    is ever dropped, however unevenly the tokens route, and those of experts
+    held elsewhere add nothing.  For ``t`` up to about the chip's
+    FLOP-per-byte ridge (about 240 rows on a v5e) that costs no more than
+    reading the held experts' weights, which a step with ``t * k`` at least
+    ``E_l`` assignments mostly has to do anyway.  Grouped GEMMs
+    (``lax.ragged_dot``) compile on the TPU to custom calls that make the
+    layer scan copy every layer's expert stack first: on a v5e 2x2 serving
+    DeepSeek-V2-Lite those copies took 22 ms of a 64 ms decode step, and the
+    grouped GEMMs of a 256-token chunk 31 ms of 63."""
+    t, k = eidx.shape
+    e_loc = p["w1"].shape[0]
+    a1 = jnp.einsum("td,edf->etf", ht, p["w1"])
+    a3 = jnp.einsum("td,edf->etf", ht, p["w3"])
+    out = jnp.einsum("etf,efd->etd", jax.nn.silu(a1) * a3, p["w2"])
+    local = eidx.reshape(-1) - e_start                   # [t*k]
+    held = (local >= 0) & (local < e_loc)
+    src = jnp.repeat(jnp.arange(t), k)                   # token of each row
+    vals = out[jnp.clip(local, 0, e_loc - 1), src]       # [t*k, D]
+    vals = jnp.where(held[:, None], vals, 0)
+    return jax.ops.segment_sum(vals * gate.reshape(-1)[:, None], src,
+                               num_segments=t)

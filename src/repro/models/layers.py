@@ -17,6 +17,7 @@ layout knob (ReduceScatter over sequence vs AllReduce).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -41,18 +42,53 @@ def init_rms_norm(d: int, dtype=jnp.bfloat16):
 # ---------------------------------------------------------------------------
 # Rotary embeddings (RoPE and Qwen2-VL M-RoPE)
 # ---------------------------------------------------------------------------
-def rope_freqs(head_dim: int, theta: float) -> Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                            / head_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor ``0.1 a ln(s) + 1`` (1 when the
+    context is not stretched)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
-def apply_rope(x: Array, positions: Array, theta: float = 10000.0) -> Array:
-    """x: [B, S, H, Dh]; positions: [B, S] absolute token positions."""
+def yarn_range(head_dim: int, theta: float, yarn) -> Tuple[int, int]:
+    """The frequency slots between which YaRN ramps from extrapolation to
+    interpolation: slot ``i`` of ``head_dim / 2`` turns ``r`` times over
+    the original context where ``i = d ln(orig / (2 pi r)) / (2 ln theta)``
+    (``r`` = ``beta_fast`` for the low end, ``beta_slow`` for the high)."""
+    def slot(turns):
+        return (head_dim * math.log(yarn.original_max_position_embeddings
+                                    / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(slot(yarn.beta_fast)), 0),
+            min(math.ceil(slot(yarn.beta_slow)), head_dim - 1))
+
+
+def rope_freqs(head_dim: int, theta: float, yarn=None) -> Array:
+    """Inverse frequencies of the ``head_dim / 2`` rotary slots; with
+    ``yarn`` (``configs.base.YarnConfig``) the slots past the ramp are
+    divided by its factor, as DeepSeek-V2's YaRN does."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                             / head_dim))
+    if yarn is None:
+        return freqs
+    low, high = yarn_range(head_dim, theta, yarn)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return freqs * (ramp / yarn.factor + (1.0 - ramp))
+
+
+def apply_rope(x: Array, positions: Array, theta: float = 10000.0,
+               yarn=None) -> Array:
+    """x: [B, S, H, Dh]; positions: [B, S] absolute token positions.
+    ``yarn``: YaRN scaling of the frequencies (and of cos and sin by the
+    ratio of its two temperature factors)."""
     dh = x.shape[-1]
-    freqs = rope_freqs(dh, theta)                       # [Dh/2]
+    freqs = rope_freqs(dh, theta, yarn)                 # [Dh/2]
     ang = positions[..., None].astype(jnp.float32) * freqs  # [B,S,Dh/2]
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -113,6 +149,21 @@ def embed_lookup(table: Array, tokens: Array, ctx: TPContext,
             with jax.named_scope("seam_embed_ar"):
                 x = lax.psum(x, ctx.axis)
     return x
+
+
+def head_table(params) -> Array:
+    """The LM head's vocab-sharded ``[V, D]`` table: the model's own
+    ``head`` when it is untied, else the embedding."""
+    return params["head"] if "head" in params else params["embed"]
+
+
+def head_logits(h: Array, params) -> Array:
+    """This rank's vocab shard of the next-token logits of the rows of
+    ``h`` ([B, S, D] -> [B, S, V/TP]), kept in float32: rounded to bf16,
+    logits a rounding apart tie, and the argmax breaks the tie differently
+    on each path that computes them."""
+    return jnp.einsum("bsd,vd->bsv", h, head_table(params),
+                      preferred_element_type=jnp.float32)
 
 
 def lm_head_logits(x: Array, table: Array, ctx: TPContext) -> Array:

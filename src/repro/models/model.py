@@ -83,7 +83,8 @@ def _init_ffn(key, kind: str, cfg: ModelConfig, ep: int, tp: int, dtype,
 _MIXER_SPECS = {
     ATTN: {"wqkv": P(None, "model"), "wo": P("model", None), "norm": P(None),
            "bqkv": P("model")},
-    MLA: {"w_dq": P(None, None), "w_uq": P(None, "model"),
+    MLA: {"w_q": P(None, "model"), "w_dq": P(None, None),
+          "w_uq": P(None, "model"),
           "w_dkv": P(None, None), "w_ukv": P(None, "model"),
           "w_o": P("model", None), "q_norm": P(None), "kv_norm": P(None),
           "norm": P(None)},
@@ -227,6 +228,13 @@ def init_model(key, cfg: ModelConfig, par: ParallelConfig,
             "proj": (jax.random.normal(keys[-1], (2 * cfg.d_model, cfg.d_model))
                      * (2 * cfg.d_model) ** -0.5).astype(dtype),
         }
+    if not cfg.tie_embeddings:
+        # drawn last, from the embedding's key folded with 1, so every
+        # earlier draw (and a tied model's whole tree) is unchanged
+        params["head"] = iu.zero_pad_rows(
+            jax.random.normal(jax.random.fold_in(keys[0], 1),
+                              (cfg.vocab_size, cfg.d_model))
+            * cfg.d_model ** -0.5, v_pad).astype(dtype)
     return params
 
 
@@ -252,6 +260,8 @@ def param_specs(cfg: ModelConfig, par: ParallelConfig,
         "embed": P("model", None),
         "final_norm": P(None),
     }
+    if "head" in params:
+        specs["head"] = P("model", None)
     if lead:
         specs["lead"] = [
             _layer_spec(pat[i], cfg, par, params["lead"][i])
@@ -347,7 +357,7 @@ def _apply_ffn(kind: str, p: Dict, x: Array, ctx: TPContext,
     if kind == DENSE_FFN:
         return ffn.ffn_train(p, x, ctx, cfg.norm_eps), 0.0
     if kind == MOE_FFN:
-        return ffn.moe_train(p, x, ctx, cfg)
+        return ffn.moe_train(p, x, ctx, cfg, cfg.norm_eps)
     if kind == RWKV:
         return rwkv.rwkv_channel_train(p, x, ctx, cfg), 0.0
     raise ValueError(kind)
@@ -420,7 +430,8 @@ def forward_loss(params: Dict, batch: Dict, ctx: TPContext, cfg: ModelConfig,
 
     h, aux = backbone(params, x, ctx, cfg, par)
     h = layers.rms_norm(h, params["final_norm"], cfg.norm_eps)
-    logits = layers.lm_head_logits(h, params["embed"], ctx)  # [B, S, V/TP]
+    logits = layers.lm_head_logits(h, layers.head_table(params),
+                                   ctx)                      # [B, S, V/TP]
 
     labels = batch["labels"]
     ce = layers.vocab_parallel_xent(logits, labels, ctx, v_pad,
@@ -448,7 +459,7 @@ def _mtp_loss(params, h, batch, ctx, cfg, par, v_pad):
     x = jnp.einsum("bsd,dm->bsm", fused, mtp["proj"])
     x, _ = _block((cfg.pattern[-1][0], DENSE_FFN),
                   {"mixer": mtp["mixer"], "ffn": mtp["ffn"]}, x, ctx, cfg, par)
-    logits = layers.lm_head_logits(x, params["embed"], ctx)
+    logits = layers.lm_head_logits(x, layers.head_table(params), ctx)
     # labels shifted one extra step
     labels = batch["labels"]
     lab2 = jnp.concatenate(

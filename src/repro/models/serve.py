@@ -83,21 +83,12 @@ def _mixer_cache_spec(kind: str, cfg: ModelConfig, par: ParallelConfig,
         spec = {"k": P(dp, None, "model", None), "v": P(dp, None, "model", None)}
         return sds, spec
     if kind == MLA:
-        m = cfg.mla
-        if pool is not None:
-            nb, bs = pool
-            sds = {"c": jax.ShapeDtypeStruct((nb, bs, m.kv_lora_rank),
-                                             jnp.bfloat16),
-                   "kr": jax.ShapeDtypeStruct((nb, bs, m.qk_rope_head_dim),
-                                              jnp.bfloat16)}
-            spec = {"c": P(None, None, None), "kr": P(None, None, None)}
-            return sds, spec
-        sds = {"c": jax.ShapeDtypeStruct((batch, s_max, m.kv_lora_rank),
-                                         jnp.bfloat16),
-               "kr": jax.ShapeDtypeStruct((batch, s_max, m.qk_rope_head_dim),
-                                          jnp.bfloat16)}
-        spec = {"c": P(dp, None, None), "kr": P(dp, None, None)}
-        return sds, spec
+        # one row per token: the latent, the shared rope key and zero
+        # lanes (``attention.latent_width``)
+        shape = ((*pool,) if pool is not None else (batch, s_max)) + (
+            attention.latent_width(cfg),)
+        return ({"latent": jax.ShapeDtypeStruct(shape, jnp.bfloat16)},
+                {"latent": P(None if pool is not None else dp, None, None)})
     if kind == MAMBA:
         d_in, _, d_state, d_conv = mamba._dims(cfg, tp)
         sds = {"conv": jax.ShapeDtypeStruct((batch, d_conv - 1, d_in),
@@ -202,7 +193,7 @@ def _ffn_decode(kind: str, p: Dict, x: Array, cache: Dict, ctx,
     if kind == DENSE_FFN:
         return ffn.ffn_decode(p, x, ctx, cfg.norm_eps), cache
     if kind == MOE_FFN:
-        return ffn.moe_decode(p, x, ctx, cfg), cache
+        return ffn.moe_serve(p, x, ctx, cfg), cache
     if kind == RWKV:
         return rwkv.rwkv_channel_decode(p, x, cache, ctx, cfg)
     raise ValueError(kind)
@@ -346,7 +337,7 @@ def decode_step(params: Dict, caches: Dict, tokens: Array, pos,
         carried=block_tables is not None)
 
     h = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,vd->bsv", h, params["embed"])  # [B,1,V/TP] local
+    logits = layers.head_logits(h, params)              # [B,1,V/TP] local
     nxt = vocab_parallel_argmax(logits[:, -1], ctx, v_pad, cfg.vocab_size)
     return _step_outputs(nxt, new_caches, logits, with_logits)
 
@@ -398,7 +389,7 @@ def _ffn_prefill(kind: str, p, x, ctx, cfg, lengths=None):
     if kind == DENSE_FFN:
         return ffn.ffn_train(p, x, ctx, cfg.norm_eps), {}
     if kind == MOE_FFN:
-        y, _ = ffn.moe_train(p, x, ctx, cfg, lengths=lengths)
+        y, _ = ffn.moe_train(p, x, ctx, cfg, cfg.norm_eps, lengths=lengths)
         return y, {}
     if kind == RWKV:
         return rwkv.rwkv_channel_train(p, x, ctx, cfg, with_cache=True,
@@ -467,7 +458,7 @@ def prefill_step(params: Dict, batch: Dict, ctx: TPContext, cfg: ModelConfig,
     else:
         h_last = layers.take_rows(ctx.gather_seq(h, "head_ag"),
                                   lengths - 1)[:, None]
-    logits = jnp.einsum("bsd,vd->bsv", h_last, params["embed"])
+    logits = layers.head_logits(h_last, params)
     nxt = vocab_parallel_argmax(logits[:, -1], ctx, v_pad, cfg.vocab_size)
     return nxt[:, None], caches
 
@@ -518,10 +509,11 @@ def _ffn_chunk(kind: str, p: Dict, x: Array, cache: Dict, slot, chunk_len,
                first, ctx, cfg: ModelConfig, li=None):
     if kind == DENSE_FFN:
         return ffn.ffn_train(p, x, ctx, cfg.norm_eps), cache
-    lenv = jnp.broadcast_to(jnp.asarray(chunk_len, jnp.int32), (x.shape[0],))
     if kind == MOE_FFN:
-        y, _ = ffn.moe_train(p, x, ctx, cfg, lengths=lenv)
-        return y, cache
+        # dropless: rows past chunk_len route too, and cost a real row
+        # nothing; their outputs are never read
+        return ffn.moe_serve(p, x, ctx, cfg), cache
+    lenv = jnp.broadcast_to(jnp.asarray(chunk_len, jnp.int32), (x.shape[0],))
     if kind == RWKV:
         layer_c = _at_layer(cache, li)
         st = _slot_state(layer_c, slot)
@@ -606,6 +598,6 @@ def prefill_chunk_step(params: Dict, caches: Dict, tokens: Array,
     h = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     h_last = layers.take_rows(
         h, jnp.broadcast_to(chunk_len - 1, (h.shape[0],)))[:, None]
-    logits = jnp.einsum("bsd,vd->bsv", h_last, params["embed"])
+    logits = layers.head_logits(h_last, params)
     nxt = vocab_parallel_argmax(logits[:, -1], ctx, v_pad, cfg.vocab_size)
     return _step_outputs(nxt, new_caches, logits, with_logits)

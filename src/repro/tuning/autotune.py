@@ -512,10 +512,12 @@ def model_seam_shapes(cfg, par, tokens_per_dp: int = 2048,
         from repro.parallel.sharding import pad_heads
         mla = cfg.mla
         h_pad = pad_heads(cfg.num_heads, tp)
+        # the query up-projection reads the compressed query, or the
+        # hidden state itself where the query is not compressed
         shapes["attn_ag@q_up"] = (
             "ag", tokens_per_dp,
             h_pad * (mla.qk_nope_head_dim + mla.qk_rope_head_dim),
-            mla.q_lora_rank)
+            d if mla.q_lora_rank is None else mla.q_lora_rank)
         shapes["attn_ag@kv_up"] = (
             "ag", tokens_per_dp,
             h_pad * (mla.qk_nope_head_dim + mla.v_head_dim),

@@ -155,7 +155,7 @@ def model_logit_rmse(cfg, par, wire_dtype: Optional[str], *,
             x = x.astype(cfg.compute_dtype)
             h, _ = M.backbone(p, x, ctx, cfg, par)
             h = layers.rms_norm(h, p["final_norm"], cfg.norm_eps)
-            return layers.lm_head_logits(h, p["embed"], ctx)
+            return layers.lm_head_logits(h, layers.head_table(p), ctx)
 
         out = logits_fn(params, tokens)
         return jnp.asarray(out, jnp.float32)[..., :cfg.vocab_size]

@@ -8,13 +8,13 @@ from repro.launch.presets import production_parallel
 
 
 def test_shape_applicability_matrix():
-    """32 runnable cells + 8 documented skips per mesh."""
+    """35 runnable cells + 9 documented skips per mesh."""
     runnable = sum(
         1 for a in ARCH_IDS for s in SHAPES.values()
         if shape_applicable(get_config(a), s))
-    assert runnable == 32
-    skipped = 10 * 4 - runnable
-    assert skipped == 8
+    assert runnable == 35
+    skipped = 11 * 4 - runnable
+    assert skipped == 9
     # only the sub-quadratic archs keep long_500k
     keep = [a for a in ARCH_IDS
             if shape_applicable(get_config(a), SHAPES["long_500k"])]
